@@ -104,11 +104,6 @@ def hlo_cost(fn: Callable, *args) -> dict:
     attacks)."""
     compiled = jax.jit(fn).lower(*args).compile()
     cost = compiled.cost_analysis()
-    # Older jax returns a one-element list of dicts.  Same shim as
-    # repro.launch.dryrun._cost_dict — duplicated on purpose: importing
-    # dryrun here would run its import-time XLA_FLAGS setup.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0))}
 

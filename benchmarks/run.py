@@ -57,6 +57,8 @@ def main(argv=None) -> int:
     ap.add_argument("--quick", action="store_true",
                     help="smaller grids (CI mode)")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.configure()
     t0 = time.time()
 
     for bench in args.benches:

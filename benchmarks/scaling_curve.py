@@ -1,9 +1,13 @@
-"""Device-scaling curve: LM train-step throughput 1 -> 8 devices.
+"""Device-scaling curve: LM train-step throughput over 1..N devices.
 
 Each point runs the explicit data-parallel shard_map driver
-(``repro.distributed.data_parallel``) on an n-device forced host mesh in
-its own subprocess — XLA fixes the host device count at backend init, so
-the parent process cannot sweep it in-process.  Rows carry tokens/s, the
+(``repro.distributed.data_parallel``) on an n-device mesh, all points in
+this one process: a device belongs to one process at a time, so a child
+per point could not reach a chip the parent holds.  On the CPU, XLA fixes
+the host device count when the backend starts, so :func:`run` asks for the
+widest point's count first — which takes effect only when nothing has
+touched the backend yet (``python -m benchmarks.run scaling``); points
+wider than the devices that exist are left out.  Rows carry tokens/s, the
 parallel efficiency vs the 1-device point, and the gradient wire bytes
 the all-reduce moves per step (uncompressed f32 vs the int8
 error-feedback payload).
@@ -15,26 +19,27 @@ accelerators).
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
-import textwrap
+import time
 
 from benchmarks import common
 
-WORKER = """
-    import json, time
-    import jax, jax.numpy as jnp
-    from repro.distributed import data_parallel as dp_mod
+_FORCE_FLAG = "--xla_force_host_platform_device_count"
+
+
+def _measure(n: int, *, arch: str, steps: int, batch: int, seq: int,
+             compress: bool) -> dict:
+    import jax
+    import jax.numpy as jnp
+
     from repro.data import pipeline as data_mod
+    from repro.distributed import data_parallel as dp_mod
     from repro.launch import train as tr
 
-    n = {n}; steps = {steps}; compress = {compress}
-    tc = tr.TrainerConfig(arch={arch!r}, steps=steps, mode='xla',
+    tc = tr.TrainerConfig(arch=arch, steps=steps, mode="xla",
                           data_parallel=True, compress=compress,
-                          mesh_devices=n, batch_override={batch},
-                          seq_override={seq}, log_every=10**9)
+                          mesh_devices=n, batch_override=batch,
+                          seq_override=seq, log_every=10**9)
     trainer = tr.build_trainer(tc)
     pipe = data_mod.Pipeline(trainer.cfg, trainer.shape,
                              data_mod.DataConfig(seed=0), start_step=0,
@@ -46,49 +51,36 @@ WORKER = """
         _, b = next(it)
         return jax.tree_util.tree_map(jnp.asarray, b)
 
-    p, o, m = trainer.step_fn(p, o, next_batch())      # compile
-    jax.block_until_ready(m['loss'])
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        p, o, m = trainer.step_fn(p, o, next_batch())
-    jax.block_until_ready(m['loss'])
-    dt = time.perf_counter() - t0
-    pipe.close()
-    tokens = {batch} * {seq} * steps
-    print(json.dumps({{
-        'devices': n, 'compress': compress,
-        'tokens_per_s': tokens / dt,
-        'step_ms': dt / steps * 1e3,
-        'loss': float(m['loss']),
-        'wire_bytes_f32': dp_mod.wire_bytes(trainer.params,
-                                            compress=False),
-        'wire_bytes_int8': dp_mod.wire_bytes(trainer.params,
-                                             compress=True),
-    }}))
-"""
-
-
-def _measure(n: int, *, arch: str, steps: int, batch: int, seq: int,
-             compress: bool) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8")
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    code = textwrap.dedent(WORKER).format(
-        n=n, steps=steps, compress=compress, arch=arch, batch=batch,
-        seq=seq)
-    out = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True, env=env,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"scaling worker (n={n}) failed:\n"
-                           + out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    try:
+        p, o, m = trainer.step_fn(p, o, next_batch())      # compile
+        jax.block_until_ready(m["loss"])
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            p, o, m = trainer.step_fn(p, o, next_batch())
+        jax.block_until_ready(m["loss"])
+        dt = time.perf_counter() - t0
+    finally:
+        pipe.close()
+    tokens = batch * seq * steps
+    return {
+        "devices": n, "compress": compress,
+        "tokens_per_s": tokens / dt,
+        "step_ms": dt / steps * 1e3,
+        "loss": float(m["loss"]),
+        "wire_bytes_f32": dp_mod.wire_bytes(trainer.params, compress=False),
+        "wire_bytes_int8": dp_mod.wire_bytes(trainer.params, compress=True),
+    }
 
 
 def run(device_counts=(1, 2, 4, 8), arch="deepseek-7b", steps=6,
         batch=8, seq=32, out_json="results/bench/scaling_curve.json"):
+    flags = os.environ.get("XLA_FLAGS", "")
+    if _FORCE_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} {_FORCE_FLAG}={max(device_counts)}".strip())
+    import jax
+    n_devices = len(jax.devices())
+    device_counts = [n for n in device_counts if n <= n_devices]
     rows = []
     base = None
     for n in device_counts:

@@ -11,10 +11,12 @@ import tempfile
 import numpy as np
 
 from repro.distributed import fault_tolerance as ft
+from repro.launch import compile_cache
 from repro.launch.train import TrainerConfig, train
 
 
 def main() -> None:
+    compile_cache.configure()
     workdir = tempfile.mkdtemp(prefix="repro_ft_")
     tc = lambda d: TrainerConfig(arch="deepseek-7b", reduced=True,  # noqa: E731
                                  steps=24, ckpt_dir=d, ckpt_every=8,

@@ -12,7 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import api
+from repro.launch import compile_cache
 from repro.models import cnn
+
+compile_cache.configure()
 
 # 1. the model: an ordinary JAX function + its parameters
 #    (paper: model = torchvision.models.vgg16(...))

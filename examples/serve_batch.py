@@ -11,6 +11,7 @@ import time
 
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.launch.engine import Request
 from repro.launch.serve import ServeConfig, Server
 
@@ -31,6 +32,7 @@ def main() -> None:
                     help="print tokens as the scheduler commits them "
                          "(Engine.run(on_token=...))")
     args = ap.parse_args()
+    compile_cache.configure()
 
     sc = ServeConfig(arch=args.arch, mode=args.mode, batch=args.slots,
                      prompt_len=args.prompt_len, new_tokens=args.new_tokens,

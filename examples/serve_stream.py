@@ -42,10 +42,11 @@ def main() -> None:
 
     import numpy as np
 
-    from repro.launch import mesh as mesh_mod
+    from repro.launch import compile_cache, mesh as mesh_mod
     from repro.launch.engine import Request
     from repro.launch.serve import ServeConfig, Server
 
+    compile_cache.configure()
     sc = ServeConfig(arch=args.arch, mode=args.mode, batch=args.slots,
                      prompt_len=args.prompt_len, new_tokens=args.new_tokens,
                      max_len=args.prompt_len + args.new_tokens + 1)

@@ -10,6 +10,7 @@ accelerator (on CPU it is slow; the default proves the loop end-to-end).
 """
 import argparse
 
+from repro.launch import compile_cache
 from repro.launch.train import TrainerConfig, train
 
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--hundred-m", action="store_true",
                     help="~100M-param config (accelerator-sized)")
     args = ap.parse_args()
+    compile_cache.configure()
 
     if args.hundred_m:
         # ~100M params: 8L x d512 x ffn2048, 32k vocab at seq 512

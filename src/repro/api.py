@@ -192,7 +192,8 @@ class OptimizedFn:
                                         kernel_dispatch=self.kernel_dispatches,
                                         autotune=self.autotune_decisions,
                                         verify=self.verify_findings,
-                                        partitions=self.partitions)
+                                        partitions=self.partitions,
+                                        mode=self.config.mode)
 
     def explain(self) -> str:
         """Human-readable :meth:`report`."""
@@ -283,7 +284,7 @@ def _floor_whole_function(tuner, net: OptimizedFn, fn: Callable,
         "kind": "function", "name": tr.graph.name,
         "sig": _graph_signature(tr.graph),
         "avals": [[list(s), str(d)] for s, d in tr.leaf_avals],
-        "mode": config.mode, "interpret": config.interpret,
+        "mode": config.mode,
         "differentiable": config.differentiable,
         "kernel_registry": config.kernel_registry,
         "backend": jax.default_backend(),

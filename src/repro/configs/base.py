@@ -151,7 +151,6 @@ def applicable_shapes(cfg: ModelConfig) -> dict[str, ShapeConfig]:
 class RuntimeConfig:
     """Execution knobs threaded through model apply functions."""
     mode: str = "xla"                  # 'brainslug' | 'xla' | 'barrier'
-    interpret: bool = True             # Pallas interpret (CPU)
     remat: str = "none"                # 'none' | 'dots' | 'full'
     # --- serving KV-cache layout ------------------------------------------
     # 'dense'  — every batch slot reserves max_len contiguous KV columns

@@ -37,7 +37,6 @@ LAYOUTS = analyzer.LAYOUTS
 class OptimizeConfig:
     mode: str = "xla"            # 'brainslug' | 'xla' | 'barrier'
     device: resource.DeviceSpec = resource.TPU_V5E
-    interpret: bool = True       # Pallas interpret mode (CPU validation)
     itemsize: int = 4
     max_steps_per_sequence: int | None = None
     # Size collapse plans for training: the generated rows backward holds
@@ -145,6 +144,10 @@ class StackCoverage:
     n_sequences: int
     hbm_breadth_bytes: int      # breadth-first traffic of this stack
     hbm_depth_bytes: int        # planned depth-first traffic
+    layout: str = "rows"        # 'rows' | 'nhwc'
+    #: how the stack executes: 'generated' (the brainslug Pallas kernels),
+    #: 'xla' or 'barrier' — the committed variant when autotuned
+    execution: str = "xla"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -271,8 +274,8 @@ class CoverageReport:
         for s in self.stacks:
             ratio = s.hbm_breadth_bytes / max(s.hbm_depth_bytes, 1)
             lines.append(
-                f"  stack {s.name:28s} ops={s.n_ops:3d} "
-                f"seqs={s.n_sequences}  HBM "
+                f"  stack {s.name:28s} {s.layout:4s} {s.execution:9s} "
+                f"ops={s.n_ops:3d} seqs={s.n_sequences}  HBM "
                 f"{s.hbm_breadth_bytes / 2**20:8.2f} MiB -> "
                 f"{s.hbm_depth_bytes / 2**20:8.2f} MiB  ({ratio:.2f}x)")
         for k in self.kernels:
@@ -320,14 +323,15 @@ def coverage_report(segments, plans: Mapping[int, collapse.CollapsePlan],
                     autotune: Mapping[
                         int, autotune_mod.Decision] | None = None,
                     verify: tuple = (),
-                    partitions: "partition_mod.PartitionPlan | None" = None
-                    ) -> CoverageReport:
+                    partitions: "partition_mod.PartitionPlan | None" = None,
+                    mode: str = "xla") -> CoverageReport:
     """Build the per-stack coverage + planned-HBM-traffic report for a
     rewritten network (shared by :class:`OptimizedNet` and the traced-path
     ``repro.api.OptimizedFn``).  ``autotune`` maps segment index (or -1
     for the function-level floor) to its committed decision; ``verify``
     carries the static verifier's compile-time findings; ``partitions``
-    is the mesh partition plan (None for single-device compiles)."""
+    is the mesh partition plan (None for single-device compiles);
+    ``mode`` is the configured execution mode of the stacks."""
     kernel_dispatch = kernel_dispatch or {}
     mesh_axes: tuple = ()
     dist: list[DistCoverage] = []
@@ -367,11 +371,15 @@ def coverage_report(segments, plans: Mapping[int, collapse.CollapsePlan],
             bf = resource.breadth_first_traffic(seg.stack, in_shapes,
                                                 itemsize)
             df = resource.depth_first_traffic(plan, in_shapes, itemsize)
+            decision = (autotune or {}).get(idx)
+            run = decision.variant if decision is not None else mode
             stacks.append(StackCoverage(
                 name=seg.stack.name, n_ops=len(seg.stack.ops),
                 kinds=tuple(op.kind.value for op in seg.stack.ops),
                 n_sequences=len(plan.sequences),
-                hbm_breadth_bytes=bf, hbm_depth_bytes=df))
+                hbm_breadth_bytes=bf, hbm_depth_bytes=df,
+                layout=seg.stack.layout,
+                execution="generated" if run == "brainslug" else run))
         elif seg.op.kind == ir.OpKind.KERNEL:
             d = kernel_dispatch.get(idx)
             kernels.append(KernelCoverage(
@@ -459,7 +467,8 @@ class OptimizedNet:
                                kernel_dispatch=self.kernel_dispatches,
                                autotune=self.autotune_decisions,
                                verify=self.verify_findings,
-                               partitions=self.partitions)
+                               partitions=self.partitions,
+                               mode=self.config.mode)
 
     def explain(self) -> str:
         """Human-readable :meth:`report` (ops captured vs. left opaque,
@@ -570,7 +579,7 @@ def compile_stacks(segments, shapes: Mapping[str, tuple[int, ...]],
         part = partitions.get(idx) if partitions is not None else None
         if seg.is_stack:
             executors[idx] = codegen.compile_plan(
-                plans[idx], mode=modes[idx], interpret=config.interpret,
+                plans[idx], mode=modes[idx],
                 cache_size=config.code_cache_size,
                 mesh=exec_mesh, part=part)
         elif seg.op.kind == ir.OpKind.KERNEL:
@@ -580,7 +589,7 @@ def compile_stacks(segments, shapes: Mapping[str, tuple[int, ...]],
                 if tuned is not None:
                     decisions[idx], backend, reason = tuned
             executors[idx], dispatches[idx] = codegen.compile_kernel_op(
-                seg.op, mode=config.mode, interpret=config.interpret,
+                seg.op, mode=config.mode,
                 cache_size=config.code_cache_size, backend=backend,
                 reason=reason, mesh=exec_mesh, part=part)
     return executors, plans, dispatches, decisions, findings, partitions
@@ -624,7 +633,6 @@ def optimize_stack(program: ir.StackProgram,
         max_steps_per_sequence=config.max_steps_per_sequence,
         differentiable=config.differentiable)
     return codegen.compile_plan(plan, mode=config.mode,
-                                interpret=config.interpret,
                                 cache_size=config.code_cache_size)
 
 

@@ -309,9 +309,10 @@ def pool2d_patch_vjp(op: ir.OpNode, x: Array, y: Array, g: Array) -> Array:
     ``x`` is the pool's input with padding already applied — out-of-image
     positions hold :func:`pool_neutral` — with spatial axes at ``(-3, -2)``;
     ``y``/``g`` are the pool output and its cotangent at the matching output
-    extent.  Works unchanged on a halo-grown VMEM tile ``(eh, ew, C)``
-    (inside the generated nhwc backward kernel) and on padded full images
-    ``(N, Hp, Wp, C)`` (the oracle path).
+    extent, here on padded full images ``(N, Hp, Wp, C)`` (the oracle
+    path).  The generated nhwc backward kernel applies the same rule to its
+    VMEM tile through strided refs
+    (``repro.kernels.fused_stack.nhwc_bwd._pool_patch_vjp``).
 
     Max ties follow the jax/XLA ``select_and_scatter`` convention: the first
     maximal element in row-major window order takes the whole cotangent.

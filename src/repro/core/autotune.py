@@ -21,8 +21,9 @@ skip the search entirely:
 * location — ``OptimizeConfig.autotune_cache_dir``, else
   ``$REPRO_AUTOTUNE_CACHE``, else ``~/.cache/repro/autotune/``;
 * key — sha256 over the canonical JSON of (kind, structural signature,
-  shapes, dtypes/itemsize, requested mode, interpret, XLA backend); the
-  jax + repro versions ride inside the entry and are verified on load;
+  shapes, dtypes/itemsize, requested mode, XLA backend — which also
+  decides Pallas interpret mode); the jax + repro versions ride inside
+  the entry and are verified on load;
 * write — the checkpointer's atomic tmp-then-rename idiom (fsync before
   rename), so a killed process can never leave a half-written entry;
 * defense in depth — schema version + per-entry checksum; corrupt,
@@ -517,7 +518,7 @@ def tune_stack(tuner: Autotuner, stack: ir.StackProgram,
                                if k in stack_params),
         "itemsize": config.itemsize,
         "device": getattr(config.device, "name", str(config.device)),
-        "mode": requested, "interpret": config.interpret,
+        "mode": requested,
         "differentiable": config.differentiable,
         "max_steps": config.max_steps_per_sequence,
         "backend": jax.default_backend(),
@@ -528,7 +529,7 @@ def tune_stack(tuner: Autotuner, stack: ir.StackProgram,
     def make_builder(mode: str, plan: Any) -> Callable[[], list]:
         def build() -> list:
             ex = codegen.compile_plan(
-                plan, mode=mode, interpret=config.interpret,
+                plan, mode=mode,
                 cache_size=config.code_cache_size)
             phases: list = [("fwd", ex, (inputs, params))]
             if config.differentiable:
@@ -572,7 +573,6 @@ def tune_kernel(tuner: Autotuner, op: ir.OpNode, config
         "static": repr(ir._freeze(
             {k: v for k, v in op.attrs.items()
              if k not in codegen._KERNEL_PLUMBING_ATTRS})),
-        "interpret": config.interpret,
         "backend": jax.default_backend(),
     }
     args = tuple(synth_array(s, d, seed=i)
@@ -581,7 +581,7 @@ def tune_kernel(tuner: Autotuner, op: ir.OpNode, config
     def make_builder(backend) -> Callable[[], list]:
         def build() -> list:
             inner = codegen.kernel_inner(
-                op, backend=backend, interpret=config.interpret,
+                op, backend=backend,
                 cache_size=config.code_cache_size)
             return [("fwd", inner, args)]
         return build

@@ -43,28 +43,11 @@ from repro.core import collapse as collapse_mod
 from repro.core import ir
 from repro.core import registry as registry_mod
 from repro.core import verify as verify_mod
+from repro.distributed import collectives
 from repro.kernels.fused_stack import ops as fused_ops
 
 Executor = Callable[[Mapping[str, jnp.ndarray], Mapping[str, jnp.ndarray]],
                     dict[str, jnp.ndarray]]
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat shard_map: graduated from jax.experimental, and the
-    replication-checker kwarg was renamed along the way.  The checker is
-    disabled — boundary specs come from the partition planner and are
-    re-derived by the ``dist.*`` verifier invariants instead."""
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
 
 
 def _spec_axis_names(spec) -> set:
@@ -107,7 +90,8 @@ def _sharded_call(mesh, body, in_specs, out_specs):
     out_axes: set = set()
     for s in out_specs:
         out_axes |= _spec_axis_names(s)
-    fwd_sm = _shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs)
+    fwd_sm = collectives.shard_map(body, mesh, in_specs=in_specs,
+                                   out_specs=out_specs)
 
     def bwd_region(*arrays):
         prim, gouts = arrays[:n_in], arrays[n_in:]
@@ -119,8 +103,9 @@ def _sharded_call(mesh, body, in_specs, out_specs):
             fixed.append(jax.lax.psum(ct, reduce_over) if reduce_over else ct)
         return tuple(fixed)
 
-    bwd_sm = _shard_map(bwd_region, mesh,
-                        in_specs=in_specs + out_specs, out_specs=in_specs)
+    bwd_sm = collectives.shard_map(bwd_region, mesh,
+                                   in_specs=in_specs + out_specs,
+                                   out_specs=in_specs)
 
     @jax.custom_vjp
     def call(*arrays):
@@ -187,7 +172,6 @@ def _cache_put(key: tuple, value) -> None:
 
 
 def compile_plan(plan: collapse_mod.CollapsePlan, *, mode: str = "xla",
-                 interpret: bool = True,
                  cache_size: int | None = None,
                  mesh=None, part=None) -> Executor:
     """Compile a collapse plan into ``executor(inputs, params) -> outputs``.
@@ -212,7 +196,7 @@ def compile_plan(plan: collapse_mod.CollapsePlan, *, mode: str = "xla",
                                  (*part.in_specs.items(),
                                   *part.out_specs.items(),
                                   *part.param_specs.items()))))
-    key = (plan.program.signature(), mode, interpret, plan.input_shapes,
+    key = (plan.program.signature(), mode, plan.input_shapes,
            tuple((s.tile_rows, s.tile_out_h, s.tile_out_w)
                  for s in plan.sequences), dist_key)
     cached = _cache_get(key)
@@ -229,7 +213,7 @@ def compile_plan(plan: collapse_mod.CollapsePlan, *, mode: str = "xla",
             fused_ops.get_executable(
                 sub, tile_rows=seq.tile_rows or 256,
                 tile_out_h=seq.tile_out_h or 8,
-                tile_out_w=seq.tile_out_w or 8, interpret=interpret)
+                tile_out_w=seq.tile_out_w or 8)
 
     def run_body(inputs: Mapping[str, jnp.ndarray],
                  params: Mapping[str, jnp.ndarray]) -> dict[str, jnp.ndarray]:
@@ -239,8 +223,7 @@ def compile_plan(plan: collapse_mod.CollapsePlan, *, mode: str = "xla",
                 sub, {k: env[k] for k in sub.inputs}, params, mode=mode,
                 tile_rows=seq.tile_rows or 256,
                 tile_out_h=seq.tile_out_h or 8,
-                tile_out_w=seq.tile_out_w or 8,
-                interpret=interpret)
+                tile_out_w=seq.tile_out_w or 8)
             env.update(out)
         return {v: env[v] for v in plan.program.outputs}
 
@@ -288,7 +271,6 @@ def _unknown_kernel_error(op: ir.OpNode) -> verify_mod.VerifyError:
 
 
 def kernel_inner(op: ir.OpNode, *, backend: registry_mod.KernelType,
-                 interpret: bool = True,
                  cache_size: int | None = None) -> Callable:
     """The positional compiled closure for one KERNEL op on an explicit
     backend.  Cached on kernel id + backend + static attrs only, so
@@ -303,8 +285,7 @@ def kernel_inner(op: ir.OpNode, *, backend: registry_mod.KernelType,
         raise _unknown_kernel_error(op) from None
     static = {k: v for k, v in op.attrs.items()
               if k not in _KERNEL_PLUMBING_ATTRS}
-    key = ("kernel", entry.name, backend.value, interpret,
-           ir._freeze(static))
+    key = ("kernel", entry.name, backend.value, ir._freeze(static))
     inner = _cache_get(key)
     if inner is not None:
         return inner
@@ -313,8 +294,8 @@ def kernel_inner(op: ir.OpNode, *, backend: registry_mod.KernelType,
     out_dtype = op.attrs["out_dtype"]
 
     if backend is registry_mod.KernelType.PALLAS:
-        call = lambda *arrays: entry.pallas(list(arrays), static,  # noqa: E731
-                                            interpret)
+        call = lambda *arrays: entry.pallas(list(arrays),  # noqa: E731
+                                            static)
         if entry.vjp == "ref":
             # entry declares no custom rule on its pallas path:
             # wrap it so jax.grad recomputes through the jnp twin
@@ -333,7 +314,6 @@ def kernel_inner(op: ir.OpNode, *, backend: registry_mod.KernelType,
 
 
 def compile_kernel_op(op: ir.OpNode, *, mode: str = "xla",
-                      interpret: bool = True,
                       cache_size: int | None = None,
                       backend: registry_mod.KernelType | None = None,
                       reason: str | None = None,
@@ -373,7 +353,7 @@ def compile_kernel_op(op: ir.OpNode, *, mode: str = "xla",
                                 for i in range(len(slots))),
             "out_shape": tuple(part.shard_shapes[out_name])})
         shard_inner = kernel_inner(shard_op, backend=dispatch.backend,
-                                   interpret=interpret, cache_size=cache_size)
+                                   cache_size=cache_size)
         tupled = _sharded_call(
             mesh, lambda *arrays: (shard_inner(*arrays),),
             in_specs=tuple(part.in_specs[f"arg{i}"]
@@ -382,7 +362,7 @@ def compile_kernel_op(op: ir.OpNode, *, mode: str = "xla",
         inner = lambda *arrays: tupled(*arrays)[0]  # noqa: E731
     else:
         inner = kernel_inner(op, backend=dispatch.backend,
-                             interpret=interpret, cache_size=cache_size)
+                             cache_size=cache_size)
 
     def executor(inputs: Mapping[str, jnp.ndarray],
                  params: Mapping[str, jnp.ndarray]) -> dict[str, jnp.ndarray]:

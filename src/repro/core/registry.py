@@ -83,7 +83,7 @@ class KernelDispatch:
 class KernelEntry:
     """One registry row: the two backends plus dispatch policy.
 
-    ``pallas(args, attrs, interpret)`` and ``ref(args, attrs)`` take the
+    ``pallas(args, attrs)`` and ``ref(args, attrs)`` take the
     operand arrays in the slot order the matcher recorded.  ``constraints``
     returns a human-readable reason string when the pallas kernel cannot
     take these shapes (-> REF fallback), or None.  ``vjp`` declares where
@@ -95,7 +95,7 @@ class KernelEntry:
     """
 
     name: str
-    pallas: Callable[[list, Mapping, bool], jnp.ndarray]
+    pallas: Callable[[list, Mapping], jnp.ndarray]
     ref: Callable[[list, Mapping], jnp.ndarray]
     constraints: Callable[[tuple, Mapping], str | None]
     vjp: str = "custom"
@@ -127,11 +127,11 @@ def _as_bhsd(x: jnp.ndarray) -> jnp.ndarray:
     return x if x.ndim == 4 else x[:, None]
 
 
-def _attention_pallas(args: list, attrs: Mapping, interpret: bool):
+def _attention_pallas(args: list, attrs: Mapping):
     q, k, v = args
     out = attn_ops.flash_attention(
         _as_bhsd(q), _as_bhsd(k), _as_bhsd(v), attrs["causal"], 128, 128,
-        interpret, attrs["scale"])
+        attrs["scale"])
     return out[:, 0] if q.ndim == 3 else out
 
 
@@ -150,10 +150,9 @@ def _attention_constraints(arg_shapes: tuple, attrs: Mapping) -> str | None:
     return None
 
 
-def _rmsnorm_pallas(args: list, attrs: Mapping, interpret: bool):
+def _rmsnorm_pallas(args: list, attrs: Mapping):
     x, g = args
-    return rms_ops.rmsnorm_value(x, jnp.reshape(g, (-1,)),
-                                 eps=attrs["eps"], interpret=interpret)
+    return rms_ops.rmsnorm_value(x, jnp.reshape(g, (-1,)), eps=attrs["eps"])
 
 
 def _rmsnorm_ref(args: list, attrs: Mapping):
@@ -169,8 +168,8 @@ def _rmsnorm_constraints(arg_shapes: tuple, attrs: Mapping) -> str | None:
     return None
 
 
-def _swiglu_pallas(args: list, attrs: Mapping, interpret: bool):
-    return swiglu_ops.swiglu(args[0], args[1], attrs["act"], 256, interpret)
+def _swiglu_pallas(args: list, attrs: Mapping):
+    return swiglu_ops.swiglu(args[0], args[1], attrs["act"], 256)
 
 
 def _swiglu_ref(args: list, attrs: Mapping):
@@ -184,10 +183,10 @@ def _swiglu_constraints(arg_shapes: tuple, attrs: Mapping) -> str | None:
     return None
 
 
-def _vocab_ce_pallas(args: list, attrs: Mapping, interpret: bool):
+def _vocab_ce_pallas(args: list, attrs: Mapping):
     h, w, labels = args
     return ce_ops.fused_gold_logp(h, w, jnp.reshape(labels, (-1,)),
-                                  128, 512, 512, interpret)
+                                  128, 512, 512)
 
 
 def _vocab_ce_ref(args: list, attrs: Mapping):

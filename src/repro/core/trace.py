@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import core as jcore
+from jax.extend import core as jex
 
 from repro.core import ir
 
@@ -115,7 +116,7 @@ _CUSTOM_GRAD_CALLS = frozenset({
 _GRAD_FENCE_PRIMS = frozenset({"stop_gradient"}) | _CUSTOM_GRAD_CALLS
 
 
-def _has_grad_fence(jaxpr: jcore.Jaxpr) -> bool:
+def _has_grad_fence(jaxpr: jex.Jaxpr) -> bool:
     """Does ``jaxpr`` (recursively) contain a gradient fence / custom rule?"""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name in _GRAD_FENCE_PRIMS:
@@ -123,23 +124,23 @@ def _has_grad_fence(jaxpr: jcore.Jaxpr) -> bool:
         for v in eqn.params.values():
             subs = v if isinstance(v, (tuple, list)) else (v,)
             for s in subs:
-                if isinstance(s, jcore.ClosedJaxpr):
+                if isinstance(s, jex.ClosedJaxpr):
                     s = s.jaxpr
-                if isinstance(s, jcore.Jaxpr) and _has_grad_fence(s):
+                if isinstance(s, jex.Jaxpr) and _has_grad_fence(s):
                     return True
     return False
 
 
-def _inner_closed_jaxpr(eqn) -> jcore.ClosedJaxpr | None:
+def _inner_closed_jaxpr(eqn) -> jex.ClosedJaxpr | None:
     key = _CALL_JAXPR_KEYS.get(eqn.primitive.name)
     if key is None:
         return None
     sub = eqn.params.get(key)
     if sub is None:
         return None
-    if isinstance(sub, jcore.Jaxpr):
-        sub = jcore.ClosedJaxpr(sub, ())
-    if not isinstance(sub, jcore.ClosedJaxpr):
+    if isinstance(sub, jex.Jaxpr):
+        sub = jex.ClosedJaxpr(sub, ())
+    if not isinstance(sub, jex.ClosedJaxpr):
         return None
     if len(sub.jaxpr.invars) != len(eqn.invars):
         return None                       # unknown arg convention: keep opaque
@@ -158,7 +159,7 @@ class _FlattenCtx:
         return i
 
 
-def _flatten(closed: jcore.ClosedJaxpr, operands: list, ctx: _FlattenCtx
+def _flatten(closed: jex.ClosedJaxpr, operands: list, ctx: _FlattenCtx
              ) -> list:
     """Inline ``closed`` into ``ctx.atoms``; returns the output operands."""
     env: dict[Any, Any] = {}
@@ -169,7 +170,7 @@ def _flatten(closed: jcore.ClosedJaxpr, operands: list, ctx: _FlattenCtx
         env[v] = _Const(c)
 
     def read(v):
-        if isinstance(v, jcore.Literal):
+        if isinstance(v, jex.Literal):
             return _Const(v.val)
         return env[v]
 
@@ -262,7 +263,7 @@ def _match_unary_values(xs: list, ys: list, aval) -> str | None:
     return None
 
 
-def _probe_call(sub: jcore.ClosedJaxpr, ins: list, eqn, ctx: _FlattenCtx
+def _probe_call(sub: jex.ClosedJaxpr, ins: list, eqn, ctx: _FlattenCtx
                 ) -> tuple[str, str | None, int] | None:
     """Try to recognize a whole sub-jaxpr call as one IR op.
 

@@ -19,6 +19,15 @@ import jax
 import jax.numpy as jnp
 
 
+def shard_map(fn, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-axis checker off — the one
+    shard_map entry point of the package.  Pallas calls inside a region
+    have no replication rule, and the boundary specs come from the
+    partition planner, re-derived by the ``dist.*`` verifier invariants."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def psum_hierarchical(x: jnp.ndarray, *, pod_axis: str = "pod",
                       data_axis: str = "data") -> jnp.ndarray:
     """All-reduce over (pod, data) as two stages: intra-pod first (fast ICI),

@@ -27,27 +27,10 @@ import dataclasses
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import collectives, compression
 from repro.optim import adamw
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat shard_map (graduated from jax.experimental; the
-    replication-checker kwarg was renamed along the way).  The checker is
-    off: pallas calls inside the region have no replication rule."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,9 +100,9 @@ def make_dp_train_step(loss_fn: Callable[[Any, Any], tuple],
             {**metrics, "loss": loss, **opt_metrics})
         return new_state, metrics
 
-    step = _shard_map(region, mesh,
-                      in_specs=(P(), P(dp.data_axis)),
-                      out_specs=(P(), P()))
+    step = collectives.shard_map(region, mesh,
+                                 in_specs=(P(), P(dp.data_axis)),
+                                 out_specs=(P(), P()))
 
     def apply(state: dict, batch: Any) -> tuple[dict, dict]:
         return step(state, batch)

@@ -10,12 +10,13 @@ via ``ppermute``.  Bubble fraction is (S-1)/(M+S-1) — reported by
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+
+from repro.distributed import collectives
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
@@ -45,11 +46,10 @@ def pipeline_apply(block_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         params = jax.tree_util.tree_map(lambda a: a[0], params)
         n_ticks = n_microbatches + n_stages - 1
         # carries become stage-varying inside the loop; mark them as such
-        # (pvary only exists on newer jax; older releases don't track
-        # varying axes, where the annotation is a no-op anyway)
-        pvary = getattr(jax.lax, "pvary", lambda v, axes: v)
-        buf = pvary(jnp.zeros_like(micro_in[0]), (axis,))
-        outputs = pvary(jnp.zeros_like(micro_in), (axis,))
+        buf = jax.lax.pcast(jnp.zeros_like(micro_in[0]), (axis,),
+                            to="varying")
+        outputs = jax.lax.pcast(jnp.zeros_like(micro_in), (axis,),
+                                to="varying")
 
         def tick(carry, t):
             buf, outputs = carry
@@ -81,15 +81,7 @@ def pipeline_apply(block_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
             jnp.where(stage == n_stages - 1, outputs, 0.0), axis)
         return outputs
 
-    # jax.shard_map graduated from jax.experimental in newer releases
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    sharded = shard_map(
-        functools.partial(stage_body),
-        mesh=mesh,
-        in_specs=(P(axis), P()),
-        out_specs=P(),
-    )
+    sharded = collectives.shard_map(stage_body, mesh,
+                                    in_specs=(P(axis), P()), out_specs=P())
     out = sharded(stage_params, micro)
     return out.reshape(b, *x.shape[1:])

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
@@ -69,8 +71,7 @@ def _kernel(scale: float, causal: bool, block_q: int, block_k: int,
 
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         *, causal: bool = True, scale: float | None = None,
-                        block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True) -> jnp.ndarray:
+                        block_q: int = 128, block_k: int = 128) -> jnp.ndarray:
     """q: (B, H, Sq, D); k, v: (B, G, Sk, D) with H a multiple of G."""
     b, h, sq, d = q.shape
     _, g, sk, _ = k.shape
@@ -108,6 +109,6 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(qp, kp, vp)
     return out[:, :, :sq, :]

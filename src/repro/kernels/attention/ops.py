@@ -21,24 +21,23 @@ STATS = DispatchStats(keys=("decode_pallas", "decode_ref",
                             "paged_decode_pallas", "paged_decode_ref"))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True,
-                    scale: float | None = None):
+                    block_k: int = 128, scale: float | None = None):
     """Flash forward + reference-recompute backward.  ``scale`` overrides
     the default ``1/sqrt(head_dim)`` score scaling (the kernel-registry
     path passes the scale it matched out of the traced graph)."""
     return flash_mod.flash_attention_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret)
+        block_k=block_k)
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, scale):
-    return flash_attention(q, k, v, causal, block_q, block_k, interpret,
+def _fwd(q, k, v, causal, block_q, block_k, scale):
+    return flash_attention(q, k, v, causal, block_q, block_k,
                            scale), (q, k, v)
 
 
-def _bwd(causal, block_q, block_k, interpret, scale, res, g):
+def _bwd(causal, block_q, block_k, scale, res, g):
     q, k, v = res
     _, vjp = jax.vjp(
         lambda q_, k_, v_: ref_mod.attention_ref(q_, k_, v_, causal=causal,
@@ -50,17 +49,13 @@ def _bwd(causal, block_q, block_k, interpret, scale, res, g):
 flash_attention.defvjp(_fwd, _bwd)
 
 
-def flash_decode(q, k, v, lengths, *, block_k: int = 512,
-                 interpret: bool = True):
+def flash_decode(q, k, v, lengths, *, block_k: int = 512):
     """Inference-only (no vjp needed on the decode path)."""
-    return decode_mod.flash_decode(q, k, v, lengths, block_k=block_k,
-                                   interpret=interpret)
+    return decode_mod.flash_decode(q, k, v, lengths, block_k=block_k)
 
 
-def paged_flash_decode(q, k_pool, v_pool, table, lengths, *,
-                       interpret: bool = True):
+def paged_flash_decode(q, k_pool, v_pool, table, lengths):
     """Block-mapped flash decode (inference-only, like ``flash_decode``):
     the (B, MB) block table routes each grid step to its physical pool
     block via scalar prefetch."""
-    return decode_mod.paged_flash_decode(q, k_pool, v_pool, table, lengths,
-                                         interpret=interpret)
+    return decode_mod.paged_flash_decode(q, k_pool, v_pool, table, lengths)

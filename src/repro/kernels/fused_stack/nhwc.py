@@ -9,17 +9,19 @@ Halo mechanics
 --------------
 BlockSpec partitions are non-overlapping, but stacked stride-1 pooling needs
 overlapping input regions.  The TPU-idiomatic answer is to keep the input in
-``ANY`` (HBM) memory space and issue an explicit windowed copy per grid cell
-(on hardware: an async DMA; under ``interpret=True``: a dynamic-slice load).
-The wrapper pre-pads the input so window origins are always in-bounds, and
-per-pool *validity masks* — computed from global coordinates with
-``broadcasted_iota`` — replace out-of-image positions with the pool's
-neutral element (−inf for max, 0 for avg), reproducing each pooling layer's
-own padding semantics exactly.  See ``ref.py`` for the oracle.
+``ANY`` (HBM) memory space and issue an explicit windowed async DMA per grid
+cell into a VMEM scratch buffer (:func:`load_halo`).  The wrapper pre-pads
+the input so window origins are always in-bounds, and per-pool *validity
+masks* — computed from global coordinates with ``broadcasted_iota`` —
+replace out-of-image positions with the pool's neutral element (−inf for
+max, 0 for avg), reproducing each pooling layer's own padding semantics
+exactly.  See ``ref.py`` for the oracle.
 
 Pooling inside the kernel is expressed as a static unrolled max/add over
-``window`` shifted strided slices of the VMEM tile — ``reduce_window`` does
-not exist inside Mosaic, shifted slices map onto plain VPU ops.
+``window`` shifted strided loads from a VMEM scratch copy of the pool's
+masked input — ``reduce_window`` does not exist inside Mosaic, and a strided
+slice of a value lowers to a gather it refuses, while a strided ``pl.ds``
+load from a ref is a plain vector load.
 
 Beyond the single-input chain, the kernel carries *broadcast side operands*
 (extra stack inputs whose every non-channel dim is 1, e.g. a saved
@@ -40,9 +42,14 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core import autodiff
 from repro.core import ir
+
+#: Lane width of a vector register: the channel (minor) dim is padded to it.
+LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,15 +128,41 @@ def write_model(n: int, oh: int, ow: int, c: int,
         "accumulate": None}]
 
 
-def _pool_tile(x: jnp.ndarray, op: ir.OpNode, out_h: int, out_w: int
+def store_lanes(ref, x: jnp.ndarray) -> None:
+    """Write an ``(h, w, C)`` value into a lane-chunked ``(C // 128, h, w,
+    128)`` VMEM ref — the layout Mosaic's strided loads and stores take
+    (their base memref must be exactly one 128-lane tile wide)."""
+    for j in range(ref.shape[0]):
+        ref[j] = x[..., j * LANES:(j + 1) * LANES]
+
+
+def load_lanes(ref, rows=slice(None), cols=slice(None)) -> jnp.ndarray:
+    """Read ``ref[:, rows, cols, :]`` of a lane-chunked ref back as one
+    ``(h, w, C)`` value."""
+    parts = [ref[j, rows, cols, :] for j in range(ref.shape[0])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
+def window(op: ir.OpNode, di: int, dj: int, out_h: int, out_w: int):
+    """The strided row/column index of window offset ``(di, dj)``."""
+    sh, sw = op.attrs["stride"]
+    return pl.ds(di, out_h, stride=sh), pl.ds(dj, out_w, stride=sw)
+
+
+def pool_window(x_ref, op: ir.OpNode, di: int, dj: int, out_h: int,
+                out_w: int) -> jnp.ndarray:
+    """The ``(out_h, out_w, C)`` inputs that window offset ``(di, dj)``
+    reads: strided loads from the VMEM ref holding the pool input."""
+    return load_lanes(x_ref, *window(op, di, dj, out_h, out_w))
+
+
+def _pool_tile(x_ref, op: ir.OpNode, out_h: int, out_w: int
                ) -> jnp.ndarray:
     kh, kw = op.attrs["window"]
-    sh, sw = op.attrs["stride"]
     acc = None
     for di in range(kh):
         for dj in range(kw):
-            part = x[di: di + (out_h - 1) * sh + 1: sh,
-                     dj: dj + (out_w - 1) * sw + 1: sw, :]
+            part = pool_window(x_ref, op, di, dj, out_h, out_w)
             if acc is None:
                 acc = part
             elif op.fn == "max":
@@ -141,35 +174,60 @@ def _pool_tile(x: jnp.ndarray, op: ir.OpNode, out_h: int, out_w: int
     return acc
 
 
-def tile_valid(shape_hw: tuple[int, int], origin: tuple, level: _Level
+def tile_valid(shape: tuple[int, int, int], origin: tuple, level: _Level
                ) -> jnp.ndarray:
-    """``(h, w, 1)`` bool mask: which tile positions lie inside the true
-    (unpadded) image at ``level``, given the tile's global ``origin``."""
-    rh = origin[0] + jax.lax.broadcasted_iota(jnp.int32, shape_hw, 0)
-    rw = origin[1] + jax.lax.broadcasted_iota(jnp.int32, shape_hw, 1)
+    """``(h, w, C)`` bool mask: which tile positions lie inside the true
+    (unpadded) image at ``level``, given the tile's global ``origin``.
+    Built at full tile shape: Mosaic cannot expand an ``(h, w)`` mask."""
+    rh = origin[0] + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    rw = origin[1] + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return ((rh >= 0) & (rh < level.image_h)
-            & (rw >= 0) & (rw < level.image_w))[..., None]
+            & (rw >= 0) & (rw < level.image_w))
+
+
+def pool_ops(program: ir.StackProgram) -> list[tuple[int, ir.OpNode]]:
+    """``(index, op)`` of every POOL2D op, in program order."""
+    return [(i, op) for i, op in enumerate(program.ops)
+            if op.kind == ir.OpKind.POOL2D]
+
+
+def tile_scratch(program: ir.StackProgram, levels: list[_Level], c: int,
+                 dtype, *, backward: bool = False) -> list:
+    """VMEM scratch of one kernel: the halo-grown input patch and the DMA
+    semaphore :func:`load_halo` fills it with, then one buffer per pool op
+    holding its masked input (:func:`run_tile`), and for the backward one
+    more per pool op accumulating its input cotangent."""
+    lv0 = levels[0]
+    pools = [pltpu.VMEM((c // LANES, levels[i].extent_h,
+                         levels[i].extent_w, LANES), dtype)
+             for i, _ in pool_ops(program)]
+    return ([pltpu.VMEM((lv0.extent_h, dma_width(lv0.extent_w, dtype), c),
+                        dtype),
+             pltpu.SemaphoreType.DMA(())]
+            + pools + (pools if backward else []))
 
 
 def run_tile(program: ir.StackProgram, levels: list[_Level],
              buf: jnp.ndarray, extras: Mapping[str, jnp.ndarray],
-             params: Mapping[str, jnp.ndarray], g0h, g0w
-             ) -> tuple[dict, dict, dict, dict]:
+             params: Mapping[str, jnp.ndarray], g0h, g0w,
+             pool_refs: Mapping[str, object]
+             ) -> tuple[dict, dict, dict]:
     """Depth-first forward of the whole op chain on one resident tile.
 
     ``buf`` is the halo-grown input patch with global origin ``(g0h, g0w)``
     (unpadded image coordinates); ``extras`` are broadcast side operands as
-    ``(1, 1, C)`` values.  Returns ``(env, origins, masked, valids)`` where
-    ``masked[op.name]``/``valids[op.name]`` are each pool's neutral-masked
-    input and validity mask — exactly what the backward's reverse sweep
-    needs.  Shared by the forward and backward kernels so the recompute
-    cannot drift from the forward.
+    ``(1, 1, C)`` values.  Each pool writes its neutral-masked input into
+    ``pool_refs[op.name]`` (VMEM scratch) and reads its windows back from
+    there.  Returns ``(env, origins, valids)`` where ``valids[op.name]`` is
+    each pool's validity mask — with the masked inputs left in
+    ``pool_refs``, exactly what the backward's reverse sweep needs.  Shared
+    by the forward and backward kernels so the recompute cannot drift from
+    the forward.
     """
     env: dict[str, jnp.ndarray] = {program.inputs[0]: buf}
     env.update(extras)
     origins: dict[str, tuple] = {name: (0, 0) for name in extras}
     origins[program.inputs[0]] = (g0h, g0w)
-    masked: dict[str, jnp.ndarray] = {}
     valids: dict[str, jnp.ndarray] = {}
 
     for i, op in enumerate(program.ops):
@@ -180,11 +238,12 @@ def run_tile(program: ir.StackProgram, levels: list[_Level],
             oh, ow = origins[op.inputs[0]]
             # mask positions outside the true image at this level; fill with
             # the pool's neutral element = that pool's padding semantics.
-            valid = tile_valid(x.shape[:2], (oh, ow), lv_in)
-            x = jnp.where(valid, x, autodiff.pool_neutral(x.dtype, op.fn))
-            masked[op.name] = x
+            valid = tile_valid(x.shape, (oh, ow), lv_in)
+            x_ref = pool_refs[op.name]
+            store_lanes(x_ref, jnp.where(
+                valid, x, autodiff.pool_neutral(x.dtype, op.fn)))
             valids[op.name] = valid
-            y = _pool_tile(x, op, lv_out.extent_h, lv_out.extent_w)
+            y = _pool_tile(x_ref, op, lv_out.extent_h, lv_out.extent_w)
             sh, sw = op.attrs["stride"]
             ph, pw = op.attrs["padding"]
             # exact by construction: origin_in = origin_out * s - p
@@ -197,7 +256,37 @@ def run_tile(program: ir.StackProgram, levels: list[_Level],
             anchor = next((v for v in op.inputs if v not in extras),
                           op.inputs[0])
             origins[op.output] = origins[anchor]
-    return env, origins, masked, valids
+    return env, origins, valids
+
+
+def sublanes(dtype) -> int:
+    """Rows of one (sublane, 128) vector tile for ``dtype``: 8 for 32-bit
+    values, 16 for 16-bit ones."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def dma_width(extent_w: int, dtype) -> int:
+    """Columns one halo DMA moves: the halo width rounded up to the sublane
+    tile, since the copy may only cut whole tiles out of the source."""
+    return -(-extent_w // sublanes(dtype)) * sublanes(dtype)
+
+
+def load_halo(src_ref, buf_ref, sem, n, h0, w0, extent_w: int
+              ) -> jnp.ndarray:
+    """Copy the halo-grown patch of image ``n`` at padded origin
+    ``(h0, w0)`` from the HBM (``pl.ANY``) input into VMEM, wait for it,
+    and return its first ``extent_w`` columns.  Overlapping patches cannot
+    be expressed as BlockSpec tiles, and a ``pl.ANY`` ref can only be read
+    by DMA.  Compiled, ``w0`` must be a sublane-tile multiple
+    (:func:`plan_geometry` checks it) and the copy is :func:`dma_width`
+    columns wide."""
+    eh, ew = buf_ref.shape[:2]
+    w0 = pl.multiple_of(w0, sublanes(buf_ref.dtype))
+    copy = pltpu.make_async_copy(
+        src_ref.at[n, pl.ds(h0, eh), pl.ds(w0, ew), :], buf_ref, sem)
+    copy.start()
+    copy.wait()
+    return buf_ref[:, :extent_w, :]
 
 
 def _kernel(program: ir.StackProgram, levels: list[_Level],
@@ -207,6 +296,9 @@ def _kernel(program: ir.StackProgram, levels: list[_Level],
     extra_refs = refs[1: 1 + n_extra]
     param_refs = refs[1 + n_extra: 1 + n_extra + n_params]
     out_ref = refs[1 + n_extra + n_params]
+    buf_ref, sem = refs[2 + n_extra + n_params: 4 + n_extra + n_params]
+    pool_refs = {op.name: ref for (_, op), ref in
+                 zip(pool_ops(program), refs[4 + n_extra + n_params:])}
 
     n = pl.program_id(0)
     pi = pl.program_id(1)
@@ -218,8 +310,8 @@ def _kernel(program: ir.StackProgram, levels: list[_Level],
     g0h = pi * out_lv.extent_h * lv0.mul_h - lv0.off_h
     g0w = pj * out_lv.extent_w * lv0.mul_w - lv0.off_w
     # load from the pre-padded array (always in-bounds)
-    buf = src_ref[n, pl.dslice(g0h + pad_off_h, lv0.extent_h),
-                  pl.dslice(g0w + pad_off_w, lv0.extent_w), :]
+    buf = load_halo(src_ref, buf_ref, sem, n, g0h + pad_off_h,
+                    g0w + pad_off_w, lv0.extent_w)
 
     # (1, C) param / broadcast-extra blocks against (h, w, C) tiles.
     extras = {name: ref[...][None] for name, ref in
@@ -227,7 +319,8 @@ def _kernel(program: ir.StackProgram, levels: list[_Level],
     params = {name: ref[...] for name, ref in
               zip(program.param_names, param_refs)}
 
-    env, _, _, _ = run_tile(program, levels, buf, extras, params, g0h, g0w)
+    env, _, _ = run_tile(program, levels, buf, extras, params, g0h, g0w,
+                         pool_refs)
     out_ref[...] = env[program.outputs[0]][None]
 
 
@@ -235,7 +328,9 @@ def plan_geometry(program: ir.StackProgram, x: jnp.ndarray,
                   extras: Mapping[str, jnp.ndarray],
                   tile_out_h: int, tile_out_w: int):
     """Shared forward/backward geometry: levels, grid, clamped tile extents,
-    and the pre-padded input (every halo load in-bounds).  Returns
+    and the pre-padded input (every halo load in-bounds, channels padded to
+    a lane multiple: the halo DMA cannot slice a part of the 128-lane
+    tile).  Returns
     ``(levels, grid, xp, (left_h, left_w), (oh, ow), (pad_oh, pad_ow),
     (th, tw))``."""
     n, h, w, c = x.shape
@@ -263,20 +358,32 @@ def plan_geometry(program: ir.StackProgram, x: jnp.ndarray,
     last_g0h = (grid[1] - 1) * th * lv0.mul_h - lv0.off_h
     last_g0w = (grid[2] - 1) * tw * lv0.mul_w - lv0.off_w
     right_h = max(0, last_g0h + lv0.extent_h - h)
-    right_w = max(0, last_g0w + lv0.extent_w - w)
-    xp = jnp.pad(x, ((0, 0), (left_h, right_h), (left_w, right_w), (0, 0)))
+    right_w = max(0, last_g0w + dma_width(lv0.extent_w, x.dtype) - w)
+    # compiled, the halo DMA cuts whole sublane tiles: every patch's padded
+    # column origin (pj * tw * mul_w) must start one
+    if (grid[2] > 1 and (tw * lv0.mul_w) % sublanes(x.dtype)
+            and not kernels.pallas_interpret()):
+        raise ValueError(
+            f"{program.name}: tile_out_w={tw} x stride {lv0.mul_w} puts "
+            f"halo origins off the {sublanes(x.dtype)}-column tile")
+    xp = jnp.pad(x, ((0, 0), (left_h, right_h), (left_w, right_w),
+                     (0, (-c) % LANES)))
     return (levels, grid, xp, (left_h, left_w), (oh, ow), (pad_oh, pad_ow),
             (th, tw))
 
 
+def lane_row(v, cp: int) -> jnp.ndarray:
+    """A per-channel vector as a ``(1, cp)`` block, zero-padded to the
+    padded channel count ``cp`` (the param / broadcast-extra convention)."""
+    v = jnp.asarray(v).reshape(1, -1)
+    return jnp.pad(v, ((0, 0), (0, cp - v.shape[-1])))
+
+
 def prep_extras(program: ir.StackProgram,
-                extras: Mapping[str, jnp.ndarray]) -> list[jnp.ndarray]:
-    """Broadcast side operands as (1, C) blocks (the param convention)."""
-    vals = []
-    for name in program.inputs[1:]:
-        v = jnp.asarray(extras[name])
-        vals.append(v.reshape(1, -1))
-    return vals
+                extras: Mapping[str, jnp.ndarray], cp: int
+                ) -> list[jnp.ndarray]:
+    """Broadcast side operands as (1, cp) blocks."""
+    return [lane_row(extras[name], cp) for name in program.inputs[1:]]
 
 
 def fused_nhwc_call(program: ir.StackProgram,
@@ -285,8 +392,7 @@ def fused_nhwc_call(program: ir.StackProgram,
                     *,
                     extras: Mapping[str, jnp.ndarray] | None = None,
                     tile_out_h: int = 8,
-                    tile_out_w: int = 8,
-                    interpret: bool = True) -> jnp.ndarray:
+                    tile_out_w: int = 8) -> jnp.ndarray:
     """Run an nhwc sequence as one fused Pallas kernel.
 
     ``x`` is the spatial input (``program.inputs[0]``); ``extras`` maps any
@@ -304,15 +410,16 @@ def fused_nhwc_call(program: ir.StackProgram,
     (levels, grid, xp, (left_h, left_w), (oh, ow), (pad_oh, pad_ow),
      (th, tw)) = plan_geometry(program, x, extras, tile_out_h, tile_out_w)
 
-    evals = prep_extras(program, extras)
-    pnames = list(program.param_names)
-    pvals = [jnp.asarray(params[p]).reshape(1, -1) for p in pnames]
+    cp = xp.shape[-1]
+    evals = prep_extras(program, extras, cp)
+    pvals = [lane_row(params[p], cp) for p in program.param_names]
 
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
-    in_specs += [pl.BlockSpec((1, v.shape[-1]), shared_block_index)
-                 for v in evals + pvals]
-    out_spec = pl.BlockSpec((1, th, tw, c), out_block_index)
-    out_shape = jax.ShapeDtypeStruct((n, oh + pad_oh, ow + pad_ow, c), x.dtype)
+    in_specs += [pl.BlockSpec((1, cp), shared_block_index)
+                 for _ in evals + pvals]
+    out_spec = pl.BlockSpec((1, th, tw, cp), out_block_index)
+    out_shape = jax.ShapeDtypeStruct((n, oh + pad_oh, ow + pad_ow, cp),
+                                     x.dtype)
 
     fn = pl.pallas_call(
         functools.partial(_kernel, program, levels, left_h, left_w,
@@ -321,7 +428,8 @@ def fused_nhwc_call(program: ir.StackProgram,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        scratch_shapes=tile_scratch(program, levels, cp, x.dtype),
+        interpret=kernels.pallas_interpret(),
     )
     out = fn(xp, *evals, *pvals)
-    return out[:, :oh, :ow, :]
+    return out[:, :oh, :ow, :c]

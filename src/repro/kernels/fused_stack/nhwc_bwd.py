@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.core import autodiff, ir
 from repro.kernels.fused_stack import nhwc
 
@@ -74,6 +75,35 @@ def write_model(program: ir.StackProgram, grid: tuple[int, int, int],
     return models
 
 
+def _pool_patch_vjp(op: ir.OpNode, x_ref, y: jnp.ndarray, g: jnp.ndarray,
+                    dx_ref) -> jnp.ndarray:
+    """In-kernel twin of :func:`repro.core.autodiff.pool2d_patch_vjp` (the
+    oracle's rule, same max tie convention): the window reads are strided
+    loads from ``x_ref`` (the pool's masked input left by the recompute),
+    and the scatter back to input positions is a strided accumulate into
+    the VMEM scratch ``dx_ref`` — Mosaic has neither strided value slices
+    nor interior padding."""
+    kh, kw = op.attrs["window"]
+    sh, sw = op.attrs["stride"]
+    oh, ow = g.shape[0], g.shape[1]
+    lanes = dx_ref.shape[-1]
+    dx_ref[...] = jnp.zeros(dx_ref.shape, dx_ref.dtype)
+    c = (g / float(kh * kw)).astype(dx_ref.dtype)        # avg: uniform
+    taken = jnp.zeros(g.shape, jnp.bool_)
+    for di in range(kh):
+        for dj in range(kw):
+            if op.fn == "max":
+                # route g to the first window position attaining the max
+                part = nhwc.pool_window(x_ref, op, di, dj, oh, ow)
+                sel = (part == y) & ~taken
+                taken = taken | sel
+                c = jnp.where(sel, g, 0).astype(dx_ref.dtype)
+            rows, cols = nhwc.window(op, di, dj, oh, ow)
+            for j in range(dx_ref.shape[0]):
+                dx_ref[j, rows, cols, :] += c[..., j * lanes:(j + 1) * lanes]
+    return nhwc.load_lanes(dx_ref)
+
+
 def _bwd_kernel(program: ir.StackProgram, levels, pad_off_h: int,
                 pad_off_w: int, n_extra: int, n_params: int, *refs) -> None:
     src_ref = refs[0]
@@ -82,7 +112,14 @@ def _bwd_kernel(program: ir.StackProgram, levels, pad_off_h: int,
     g_ref = refs[1 + n_extra + n_params]
     dx_ref = refs[2 + n_extra + n_params]
     dextra_refs = refs[3 + n_extra + n_params: 3 + 2 * n_extra + n_params]
-    dparam_refs = refs[3 + 2 * n_extra + n_params:]
+    n_out = 3 + 2 * n_extra + 2 * n_params
+    dparam_refs = refs[3 + 2 * n_extra + n_params: n_out]
+    buf_ref, sem = refs[n_out: n_out + 2]
+    pools = nhwc.pool_ops(program)
+    pool_refs = {op.name: ref for (_, op), ref in
+                 zip(pools, refs[n_out + 2: n_out + 2 + len(pools)])}
+    pool_dx_refs = {op.name: ref for (_, op), ref in
+                    zip(pools, refs[n_out + 2 + len(pools):])}
 
     n = pl.program_id(0)
     pi = pl.program_id(1)
@@ -92,8 +129,8 @@ def _bwd_kernel(program: ir.StackProgram, levels, pad_off_h: int,
     out_lv = levels[-1]
     g0h = pi * out_lv.extent_h * lv0.mul_h - lv0.off_h
     g0w = pj * out_lv.extent_w * lv0.mul_w - lv0.off_w
-    buf = src_ref[n, pl.dslice(g0h + pad_off_h, lv0.extent_h),
-                  pl.dslice(g0w + pad_off_w, lv0.extent_w), :]
+    buf = nhwc.load_halo(src_ref, buf_ref, sem, n, g0h + pad_off_h,
+                         g0w + pad_off_w, lv0.extent_w)
 
     extra_names = list(program.inputs[1:])
     extras = {name: ref[...][None] for name, ref in
@@ -102,8 +139,8 @@ def _bwd_kernel(program: ir.StackProgram, levels, pad_off_h: int,
               zip(program.param_names, param_refs)}
 
     # (1) depth-first recompute — the forward kernel's own tile function.
-    env, origins, masked, valids = nhwc.run_tile(
-        program, levels, buf, extras, params, g0h, g0w)
+    env, origins, valids = nhwc.run_tile(
+        program, levels, buf, extras, params, g0h, g0w, pool_refs)
 
     # (2) reverse sweep.  The incoming cotangent tile is zero on grid-padded
     # output rows/cols (the wrapper zero-pads g), and every op's output
@@ -118,12 +155,12 @@ def _bwd_kernel(program: ir.StackProgram, levels, pad_off_h: int,
         g = cot.pop(op.output, None)
         if g is None:                       # output never used downstream
             continue
-        valid_out = nhwc.tile_valid(g.shape[:2], origins[op.output],
+        valid_out = nhwc.tile_valid(g.shape, origins[op.output],
                                     levels[i + 1])
         g = jnp.where(valid_out, g, 0)
         if op.kind == ir.OpKind.POOL2D:
-            dx = autodiff.pool2d_patch_vjp(op, masked[op.name],
-                                           env[op.output], g)
+            dx = _pool_patch_vjp(op, pool_refs[op.name], env[op.output], g,
+                                 pool_dx_refs[op.name])
             # masking dual: the forward replaced out-of-image positions with
             # the neutral element, so their cotangent is exactly zero.
             dx = jnp.where(valids[op.name], dx, 0)
@@ -172,8 +209,7 @@ def fused_nhwc_bwd_call(program: ir.StackProgram,
                         g: jnp.ndarray,
                         *,
                         tile_out_h: int = 8,
-                        tile_out_w: int = 8,
-                        interpret: bool = True
+                        tile_out_w: int = 8
                         ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray],
                                    dict[str, jnp.ndarray]]:
     """Run the generated recompute-in-tile backward for one nhwc sequence.
@@ -190,22 +226,23 @@ def fused_nhwc_bwd_call(program: ir.StackProgram,
     lv0 = levels[0]
     eh, ew = lv0.extent_h, lv0.extent_w
 
-    # zero-pad the cotangent over the grid-padding region: padded output
-    # positions contribute no gradient.
-    gp = jnp.pad(g, ((0, 0), (0, pad_oh), (0, pad_ow), (0, 0)))
+    cp = xp.shape[-1]
+    # zero-pad the cotangent over the grid-padding region (and the padded
+    # channels): padded output positions contribute no gradient.
+    gp = jnp.pad(g, ((0, 0), (0, pad_oh), (0, pad_ow), (0, cp - c)))
 
-    evals = nhwc.prep_extras(program, extras)
+    evals = nhwc.prep_extras(program, extras, cp)
     pnames = list(program.param_names)
-    pvals = [jnp.asarray(params[p]).reshape(1, -1) for p in pnames]
+    pvals = [nhwc.lane_row(params[p], cp) for p in pnames]
 
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
-    in_specs += [pl.BlockSpec((1, v.shape[-1]), nhwc.shared_block_index)
-                 for v in evals + pvals]
-    in_specs += [pl.BlockSpec((1, th, tw, c), nhwc.out_block_index)]
+    in_specs += [pl.BlockSpec((1, cp), nhwc.shared_block_index)
+                 for _ in evals + pvals]
+    in_specs += [pl.BlockSpec((1, th, tw, cp), nhwc.out_block_index)]
 
-    out_shapes = [jax.ShapeDtypeStruct((n, grid[1], grid[2], eh, ew, c),
+    out_shapes = [jax.ShapeDtypeStruct((n, grid[1], grid[2], eh, ew, cp),
                                        x.dtype)]
-    out_specs = [pl.BlockSpec((1, 1, 1, eh, ew, c), patch_block_index)]
+    out_specs = [pl.BlockSpec((1, 1, 1, eh, ew, cp), patch_block_index)]
     # grid-summed accumulators: every cell addresses block (0, 0)
     for v in evals + pvals:
         out_shapes.append(jax.ShapeDtypeStruct(v.shape, v.dtype))
@@ -219,7 +256,9 @@ def fused_nhwc_bwd_call(program: ir.StackProgram,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
-        interpret=interpret,
+        scratch_shapes=nhwc.tile_scratch(program, levels, cp, x.dtype,
+                                         backward=True),
+        interpret=kernels.pallas_interpret(),
     )
     outs = fn(xp, *evals, *pvals, gp)
     patches = outs[0]
@@ -236,19 +275,19 @@ def fused_nhwc_bwd_call(program: ir.StackProgram,
         h0 = pi * th * lv0.mul_h - lv0.off_h + left_h
         w0 = pj * tw * lv0.mul_w - lv0.off_w + left_w
         patch = jax.lax.dynamic_slice(
-            patches, (0, pi, pj, 0, 0, 0), (n, 1, 1, eh, ew, c))[:, 0, 0]
-        cur = jax.lax.dynamic_slice(acc, (0, h0, w0, 0), (n, eh, ew, c))
+            patches, (0, pi, pj, 0, 0, 0), (n, 1, 1, eh, ew, cp))[:, 0, 0]
+        cur = jax.lax.dynamic_slice(acc, (0, h0, w0, 0), (n, eh, ew, cp))
         return jax.lax.dynamic_update_slice(acc, cur + patch,
                                             (0, h0, w0, 0))
 
     dxp = jax.lax.fori_loop(0, gh * gw, _accumulate, jnp.zeros_like(xp))
-    dx = dxp[:, left_h: left_h + h, left_w: left_w + w, :]
+    dx = dxp[:, left_h: left_h + h, left_w: left_w + w, :c]
 
     dextras: dict[str, jnp.ndarray] = {}
     for name, d in zip(program.inputs[1:], outs[1: 1 + len(evals)]):
-        dextras[name] = d.reshape(jnp.shape(extras[name])).astype(
+        dextras[name] = d[:, :c].reshape(jnp.shape(extras[name])).astype(
             jnp.asarray(extras[name]).dtype)
     dparams: dict[str, jnp.ndarray] = {}
     for pname, d in zip(pnames, outs[1 + len(evals):]):
-        dparams[pname] = d.reshape(jnp.shape(params[pname]))
+        dparams[pname] = d[:, :c].reshape(jnp.shape(params[pname]))
     return dx, dextras, dparams

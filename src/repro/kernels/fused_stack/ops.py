@@ -31,7 +31,7 @@ from typing import Callable, Mapping
 import jax
 import jax.numpy as jnp
 
-from repro.core import autodiff, ir
+from repro.core import autodiff, ir, resource
 from repro.kernels.fused_stack import nhwc, nhwc_bwd, ref, rows, rows_bwd
 
 MODES = ("brainslug", "xla", "barrier")
@@ -102,7 +102,6 @@ class FusedExecutable:
     tile_rows: int
     tile_out_h: int
     tile_out_w: int
-    interpret: bool
     call: Callable[..., tuple[jnp.ndarray, ...]]   # (in_list, p_list) -> outs
     generated_bwd: bool                            # depth-first backward?
 
@@ -125,16 +124,15 @@ def set_cache_limit(n: int) -> None:
 
 
 def get_executable(program: ir.StackProgram, *, tile_rows: int = 256,
-                   tile_out_h: int = 8, tile_out_w: int = 8,
-                   interpret: bool = True) -> FusedExecutable:
+                   tile_out_h: int = 8, tile_out_w: int = 8
+                   ) -> FusedExecutable:
     """Build (or fetch) the cached forward+backward executable for
     ``program`` at the given tile geometry, keyed on the structural
     signature so equivalent stacks share one generated pair."""
-    key = (program.signature(), tile_rows, tile_out_h, tile_out_w, interpret)
+    key = (program.signature(), tile_rows, tile_out_h, tile_out_w)
     exe = _EXEC_CACHE.get(key)
     if exe is None:
-        exe = _build_executable(program, tile_rows, tile_out_h, tile_out_w,
-                                interpret)
+        exe = _build_executable(program, tile_rows, tile_out_h, tile_out_w)
         _EXEC_CACHE[key] = exe
     _EXEC_CACHE.move_to_end(key)
     while len(_EXEC_CACHE) > _CACHE_LIMIT:
@@ -147,8 +145,7 @@ def clear_executable_cache() -> None:
 
 
 def _build_executable(program: ir.StackProgram, tile_rows: int,
-                      tile_out_h: int, tile_out_w: int,
-                      interpret: bool) -> FusedExecutable:
+                      tile_out_h: int, tile_out_w: int) -> FusedExecutable:
     names = tuple(program.inputs)
     pnames = tuple(program.param_names)
     is_nhwc = program.layout == "nhwc"
@@ -170,8 +167,7 @@ def _build_executable(program: ir.StackProgram, tile_rows: int,
                 y = nhwc.fused_nhwc_call(
                     program, in_list[0], params,
                     extras=dict(zip(names[1:], in_list[1:])),
-                    tile_out_h=tile_out_h, tile_out_w=tile_out_w,
-                    interpret=interpret)
+                    tile_out_h=tile_out_h, tile_out_w=tile_out_w)
                 return (y,)
             # spatially-extended multi-input nhwc: XLA-path fallback
             STATS.record("fwd_reference")
@@ -179,8 +175,7 @@ def _build_executable(program: ir.StackProgram, tile_rows: int,
             return tuple(out[v] for v in program.outputs)
         STATS.record("fwd_generated")
         out = rows.fused_rows_call(program, inputs, params,
-                                   tile_rows=tile_rows,
-                                   interpret=interpret)
+                                   tile_rows=tile_rows)
         return tuple(out[v] for v in program.outputs)
 
     @jax.custom_vjp
@@ -200,8 +195,7 @@ def _build_executable(program: ir.StackProgram, tile_rows: int,
             dx, dextras, dparams = nhwc_bwd.fused_nhwc_bwd_call(
                 program, in_list[0], dict(zip(names[1:], in_list[1:])),
                 dict(zip(pnames, p_list)), g[0],
-                tile_out_h=tile_out_h, tile_out_w=tile_out_w,
-                interpret=interpret)
+                tile_out_h=tile_out_h, tile_out_w=tile_out_w)
             return ((dx,) + tuple(dextras[n] for n in names[1:]),
                     tuple(dparams[p] for p in pnames))
         if generated_bwd and not is_nhwc:
@@ -210,7 +204,7 @@ def _build_executable(program: ir.StackProgram, tile_rows: int,
                 program, dict(zip(names, in_list)),
                 dict(zip(pnames, p_list)),
                 dict(zip(program.outputs, g)),
-                tile_rows=tile_rows, interpret=interpret)
+                tile_rows=tile_rows)
             return (tuple(dins[n] for n in names),
                     tuple(dparams[p] for p in pnames))
 
@@ -228,8 +222,23 @@ def _build_executable(program: ir.StackProgram, tile_rows: int,
     run.defvjp(_fwd, _bwd)
     return FusedExecutable(program=program, tile_rows=tile_rows,
                            tile_out_h=tile_out_h, tile_out_w=tile_out_w,
-                           interpret=interpret, call=run,
+                           call=run,
                            generated_bwd=generated_bwd)
+
+
+def plan_row_tile(program: ir.StackProgram,
+                  in_list: tuple[jnp.ndarray, ...]) -> int:
+    """The rows tile for one call: the planner's VMEM-budgeted extent for
+    the widest feature dim, clamped to the (sublane-rounded) row count so a
+    decode-sized batch is not padded up to a prefill-sized tile."""
+    features = max(jnp.shape(a)[-1] for a in in_list)
+    itemsize = max(jnp.dtype(a.dtype).itemsize for a in in_list)
+    tile = resource.pick_row_tile(program, features, itemsize,
+                                  resource.TPU_V5E, differentiable=True)
+    n_rows = 1
+    for d in jnp.shape(in_list[0])[:-1]:
+        n_rows *= d
+    return min(tile, resource.round_up(n_rows, resource.TPU_V5E.sublane))
 
 
 def fused_stack_apply(program: ir.StackProgram,
@@ -237,10 +246,13 @@ def fused_stack_apply(program: ir.StackProgram,
                       params: Mapping[str, jnp.ndarray],
                       *,
                       mode: str = "xla",
-                      tile_rows: int = 256,
+                      tile_rows: int | None = None,
                       tile_out_h: int = 8,
-                      tile_out_w: int = 8,
-                      interpret: bool = True) -> dict[str, jnp.ndarray]:
+                      tile_out_w: int = 8) -> dict[str, jnp.ndarray]:
+    """Run one stack in ``mode``.  ``tile_rows=None`` sizes the rows tile
+    for these inputs against the v5e VMEM budget, as the collapse planner
+    does (the joint forward+backward working set, so the same tile serves
+    the generated backward)."""
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     if mode == "barrier":
@@ -249,9 +261,11 @@ def fused_stack_apply(program: ir.StackProgram,
         return ref.fused_stack_ref(program, inputs, params)
 
     # mode == 'brainslug': differentiable Pallas dispatch.
-    exe = get_executable(program, tile_rows=tile_rows, tile_out_h=tile_out_h,
-                         tile_out_w=tile_out_w, interpret=interpret)
     in_list = tuple(inputs[n] for n in program.inputs)
+    if tile_rows is None and program.layout == "rows":
+        tile_rows = plan_row_tile(program, in_list)
+    exe = get_executable(program, tile_rows=tile_rows or 256,
+                         tile_out_h=tile_out_h, tile_out_w=tile_out_w)
     p_list = tuple(params[p] for p in program.param_names)
     outs = exe.call(in_list, p_list)
     return dict(zip(program.outputs, outs))

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.core import ir
 
 
@@ -58,21 +59,32 @@ def write_model(program: ir.StackProgram,
     return models
 
 
+def load_tile(ref) -> jnp.ndarray:
+    """Read a VMEM block for the op chain, floating values as float32.
+
+    The chain runs in float32 whatever the storage dtype: the v5e VPU has
+    no bf16 arithmetic, and Mosaic refuses the float32 scalar constants
+    that ``ir.apply_op`` would otherwise broadcast into a bf16 vector."""
+    v = ref[...]
+    return (v.astype(jnp.float32) if jnp.issubdtype(v.dtype, jnp.floating)
+            else v)
+
+
 def _kernel(program: ir.StackProgram, n_inputs: int, n_params: int,
             *refs) -> None:
     in_refs = refs[:n_inputs]
     param_refs = refs[n_inputs:n_inputs + n_params]
     out_refs = refs[n_inputs + n_params:]
 
-    env = {name: ref[...] for name, ref in zip(program.inputs, in_refs)}
+    env = {name: load_tile(ref) for name, ref in zip(program.inputs, in_refs)}
     # Params keep their (1, F) block shape; broadcasting against the
     # (tile_rows, F) tiles is free and avoids 1-D operands on TPU.
-    params = {name: ref[...] for name, ref in
+    params = {name: load_tile(ref) for name, ref in
               zip(program.param_names, param_refs)}
     for op in program.ops:
         env[op.output] = ir.apply_op(op, env, params)
     for name, ref in zip(program.outputs, out_refs):
-        ref[...] = env[name]
+        ref[...] = env[name].astype(ref.dtype)
 
 
 def flatten_rows(prog_name: str, names: list[str],
@@ -112,8 +124,7 @@ def fused_rows_call(program: ir.StackProgram,
                     inputs: Mapping[str, jnp.ndarray],
                     params: Mapping[str, jnp.ndarray],
                     *,
-                    tile_rows: int = 256,
-                    interpret: bool = True) -> dict[str, jnp.ndarray]:
+                    tile_rows: int = 256) -> dict[str, jnp.ndarray]:
     """Run a rows-layout sequence as one fused Pallas kernel.
 
     Every input must share the same leading shape ``(..., F_i)``; leading
@@ -145,7 +156,7 @@ def fused_rows_call(program: ir.StackProgram,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )
     outs = fn(*flat, *pvals)
     if not isinstance(outs, (tuple, list)):

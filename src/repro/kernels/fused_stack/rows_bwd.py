@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.core import autodiff, ir
 from repro.kernels.fused_stack import rows
 
@@ -68,8 +69,9 @@ def _bwd_kernel(program: ir.StackProgram, n_inputs: int, n_params: int,
                     n_inputs + n_params + n_outputs + n_inputs]
     dparam_refs = refs[n_inputs + n_params + n_outputs + n_inputs:]
 
-    env = {name: ref[...] for name, ref in zip(program.inputs, in_refs)}
-    params = {name: ref[...] for name, ref in
+    env = {name: rows.load_tile(ref)
+           for name, ref in zip(program.inputs, in_refs)}
+    params = {name: rows.load_tile(ref) for name, ref in
               zip(program.param_names, param_refs)}
 
     # (1) depth-first recompute: the whole op chain on the resident tile.
@@ -86,13 +88,14 @@ def _bwd_kernel(program: ir.StackProgram, n_inputs: int, n_params: int,
         row0 = pl.program_id(0) * tile_rows
         ids = row0 + jax.lax.broadcasted_iota(jnp.int32, (tile_rows, 1), 0)
         row_mask = ids < valid_rows
-    gouts = {name: ref[...] for name, ref in zip(program.outputs, g_refs)}
+    gouts = {name: rows.load_tile(ref)
+             for name, ref in zip(program.outputs, g_refs)}
     dins, dparams = autodiff.program_vjp(program, env, params, gouts,
                                          row_mask)
 
     # (3) input cotangents: one write per tile.
     for name, ref in zip(program.inputs, din_refs):
-        ref[...] = dins[name]
+        ref[...] = dins[name].astype(ref.dtype)
 
     # (4) parameter gradients: zero-init on the first grid cell, then
     # accumulate every tile's (1, F) partial into the shared block.
@@ -103,7 +106,7 @@ def _bwd_kernel(program: ir.StackProgram, n_inputs: int, n_params: int,
                 ref[...] = jnp.zeros(ref.shape, ref.dtype)
 
         for pname, ref in zip(program.param_names, dparam_refs):
-            ref[...] += dparams[pname]
+            ref[...] += dparams[pname].astype(ref.dtype)
 
 
 def fused_rows_bwd_call(program: ir.StackProgram,
@@ -111,8 +114,7 @@ def fused_rows_bwd_call(program: ir.StackProgram,
                         params: Mapping[str, jnp.ndarray],
                         cotangents: Mapping[str, jnp.ndarray],
                         *,
-                        tile_rows: int = 256,
-                        interpret: bool = True
+                        tile_rows: int = 256
                         ) -> tuple[dict[str, jnp.ndarray],
                                    dict[str, jnp.ndarray]]:
     """Run the generated recompute-in-tile backward for one sequence.
@@ -157,7 +159,7 @@ def fused_rows_bwd_call(program: ir.StackProgram,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(din_shapes + dparam_shapes),
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )
     outs = fn(*flat, *pvals, *gflat)
     if not isinstance(outs, (tuple, list)):

@@ -15,6 +15,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+from repro.core import resource
+
+#: VMEM the tiles may take: the double-buffered in/out windows plus the
+#: float32 working copies, inside the 16 MiB scoped default of a v5e.
+VMEM_BUDGET = resource.TPU_V5E.vmem_bytes // 2
+
+
+def fit_rows(block_rows: int, n_windows: int, d: int, itemsize: int) -> int:
+    """``block_rows`` cut to what fits :data:`VMEM_BUDGET`: ``n_windows``
+    double-buffered ``(rows, d)`` windows and three float32 temporaries
+    per row, in multiples of 16 rows (the bf16 sublane tile)."""
+    per_row = (2 * n_windows * itemsize + 3 * 4) * d
+    return min(block_rows, max(16, VMEM_BUDGET // per_row // 16 * 16))
+
 
 def _kernel(eps: float, has_residual: bool, x_ref, *refs) -> None:
     if has_residual:
@@ -35,8 +50,7 @@ def rmsnorm_fwd(x: jnp.ndarray,
                 residual: jnp.ndarray | None = None,
                 *,
                 eps: float = 1e-6,
-                block_rows: int = 256,
-                interpret: bool = True):
+                block_rows: int = 256):
     """Returns ``(y, h)`` where ``h = x (+ residual)`` is the new residual
     stream and ``y = rmsnorm(h) * scale``."""
     lead = x.shape[:-1]
@@ -48,7 +62,9 @@ def rmsnorm_fwd(x: jnp.ndarray,
     has_res = residual is not None
     rf = residual.reshape(rows, d) if has_res else None
 
-    block_rows = min(block_rows, max(rows, 1))
+    block_rows = min(fit_rows(block_rows, 4 if has_res else 2, d,
+                              jnp.dtype(x.dtype).itemsize),
+                     max(rows, 1))
     pad = (-rows) % block_rows
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
@@ -76,7 +92,7 @@ def rmsnorm_fwd(x: jnp.ndarray,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(*operands)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)

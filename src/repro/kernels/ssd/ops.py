@@ -10,8 +10,8 @@ from repro.kernels.ssd import chunked as chunked_mod
 from repro.kernels.ssd import ssd as kernel_mod
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def ssd(x, dt, A, B, C, D, chunk: int = 64, interpret: bool = True):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def ssd(x, dt, A, B, C, D, chunk: int = 64):
     """Pallas-accelerated SSD: intra-chunk work in the kernel, inter-chunk
     state scan at the JAX level.  Matches ``chunked.ssd_chunked`` /
     ``ref.ssd_ref`` bitwise up to f32 reassociation."""
@@ -37,8 +37,7 @@ def ssd(x, dt, A, B, C, D, chunk: int = 64, interpret: bool = True):
     a = jnp.cumsum(dta, axis=2)                          # (b,nc,L,h)
     a_bh = jnp.moveaxis(a, 3, 1)[..., None]              # (b,h,nc,L,1)
 
-    y_intra, S = kernel_mod.ssd_intra_chunk(dtx, a_bh, Bf, Cf,
-                                            interpret=interpret)
+    y_intra, S = kernel_mod.ssd_intra_chunk(dtx, a_bh, Bf, Cf)
 
     # inter-chunk state recurrence (tiny)
     lam = jnp.exp(jnp.moveaxis(a[:, :, -1], 2, 1))       # (b,h,nc)
@@ -61,11 +60,11 @@ def ssd(x, dt, A, B, C, D, chunk: int = 64, interpret: bool = True):
     return y.astype(x.dtype)
 
 
-def _fwd(x, dt, A, B, C, D, chunk, interpret):
-    return ssd(x, dt, A, B, C, D, chunk, interpret), (x, dt, A, B, C, D)
+def _fwd(x, dt, A, B, C, D, chunk):
+    return ssd(x, dt, A, B, C, D, chunk), (x, dt, A, B, C, D)
 
 
-def _bwd(chunk, interpret, res, g):
+def _bwd(chunk, res, g):
     x, dt, A, B, C, D = res
     has_d = D is not None
 

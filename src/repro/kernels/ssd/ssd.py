@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _kernel(chunk: int, dtx_ref, a_ref, b_ref, c_ref, y_ref, s_ref) -> None:
     dtx = dtx_ref[0, 0, 0]                       # (L, P) f32
@@ -48,7 +50,7 @@ def _kernel(chunk: int, dtx_ref, a_ref, b_ref, c_ref, y_ref, s_ref) -> None:
 
 
 def ssd_intra_chunk(dtx: jnp.ndarray, a: jnp.ndarray, B: jnp.ndarray,
-                    C: jnp.ndarray, *, interpret: bool = True):
+                    C: jnp.ndarray):
     """dtx: (b,h,nc,L,P) f32; a: (b,h,nc,L,1) f32; B/C: (b,nc,L,N) f32.
     Returns (y_intra (b,h,nc,L,P), S (b,h,nc,N,P))."""
     b, h, nc, L, p = dtx.shape
@@ -71,6 +73,6 @@ def ssd_intra_chunk(dtx: jnp.ndarray, a: jnp.ndarray, B: jnp.ndarray,
             jax.ShapeDtypeStruct((b, h, nc, L, p), jnp.float32),
             jax.ShapeDtypeStruct((b, h, nc, n, p), jnp.float32),
         ),
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(dtx, a, B, C)
     return y, s

@@ -13,18 +13,16 @@ from repro.kernels.swiglu import swiglu as kernel_mod
 ACTS = tuple(kernel_mod._ACTS)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def swiglu(gate, up, act: str = "silu", block_rows: int = 256,
-           interpret: bool = True):
-    return kernel_mod.swiglu_fwd(gate, up, act=act, block_rows=block_rows,
-                                 interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def swiglu(gate, up, act: str = "silu", block_rows: int = 256):
+    return kernel_mod.swiglu_fwd(gate, up, act=act, block_rows=block_rows)
 
 
-def _fwd(gate, up, act, block_rows, interpret):
-    return swiglu(gate, up, act, block_rows, interpret), (gate, up)
+def _fwd(gate, up, act, block_rows):
+    return swiglu(gate, up, act, block_rows), (gate, up)
 
 
-def _bwd(act, block_rows, interpret, res, g):
+def _bwd(act, block_rows, res, g):
     gate, up = res
     _, vjp = jax.vjp(lambda a, b: ref_mod.swiglu_ref(a, b, act=act), gate, up)
     return vjp(g)

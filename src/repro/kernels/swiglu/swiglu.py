@@ -1,7 +1,7 @@
 """Fused SwiGLU / GeGLU gate kernel: ``y = act(gate) * up``.
 
-Depth-first over ``(block_rows, F)`` tiles: gate and up are each read once,
-the activation and product happen in VMEM, one write.  Breadth-first
+Depth-first over ``(block_rows, block_f)`` tiles: gate and up are each read
+once, the activation and product happen in VMEM, one write.  Breadth-first
 materializes ``act(gate)`` to HBM first (an extra full read+write of an
 ``(T, d_ff)`` tensor — the largest activation in the block).
 """
@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import kernels
 
 _ACTS = {
     "silu": jax.nn.silu,
@@ -26,8 +28,22 @@ def _kernel(act: str, g_ref, u_ref, y_ref) -> None:
                   * u_ref[...])
 
 
+#: Widest feature block: three double-buffered (rows, block) windows plus
+#: the float32 activation stay well inside the default scoped VMEM.
+MAX_BLOCK_F = 1024
+
+
+def feature_block(f: int) -> int:
+    """The widest lane multiple (128) that divides ``f``, up to
+    :data:`MAX_BLOCK_F`; a width that is no lane multiple is taken whole."""
+    if f % 128:
+        return f
+    return max(b for b in range(128, min(f, MAX_BLOCK_F) + 1, 128)
+               if f % b == 0)
+
+
 def swiglu_fwd(gate: jnp.ndarray, up: jnp.ndarray, *, act: str = "silu",
-               block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
+               block_rows: int = 256) -> jnp.ndarray:
     if act not in _ACTS:
         raise ValueError(f"unknown activation {act!r}")
     lead = gate.shape[:-1]
@@ -42,13 +58,16 @@ def swiglu_fwd(gate: jnp.ndarray, up: jnp.ndarray, *, act: str = "silu",
     if pad:
         gf = jnp.pad(gf, ((0, pad), (0, 0)))
         uf = jnp.pad(uf, ((0, pad), (0, 0)))
-    tile = pl.BlockSpec((block_rows, f), lambda i: (i, 0))
+    # the gate is elementwise, so the feature axis tiles freely: a whole
+    # (block_rows, d_ff) row block does not fit VMEM at LM widths
+    block_f = feature_block(f)
+    tile = pl.BlockSpec((block_rows, block_f), lambda i, j: (i, j))
     y = pl.pallas_call(
         functools.partial(_kernel, act),
-        grid=((rows + pad) // block_rows,),
+        grid=((rows + pad) // block_rows, f // block_f),
         in_specs=[tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows + pad, f), gate.dtype),
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(gf, uf)
     return y[:rows].reshape(*lead, f)

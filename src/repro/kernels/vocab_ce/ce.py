@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -1e30
 
 
@@ -77,7 +79,7 @@ def _kernel(block_v: int, v_real: int, h_ref, w_ref, lab_ref, lse_ref,
 
 def fused_ce_fwd(h: jnp.ndarray, w: jnp.ndarray, labels: jnp.ndarray,
                  *, block_rows: int = 128, block_v: int = 512,
-                 block_d: int = 512, interpret: bool = True
+                 block_d: int = 512
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """h: (T, D); w: (D, V); labels: (T,) int32 (may exceed V-1 for pad).
     Returns (logsumexp (T,), gold_logit (T,)) in f32 — the per-row NLL is
@@ -119,6 +121,6 @@ def fused_ce_fwd(h: jnp.ndarray, w: jnp.ndarray, labels: jnp.ndarray,
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.pallas_interpret(),
     )(hp, wp, labp)
     return lse[:t, 0], gold[:t, 0]

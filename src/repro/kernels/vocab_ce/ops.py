@@ -13,28 +13,28 @@ import jax.numpy as jnp
 from repro.kernels.vocab_ce import ce as kernel_mod
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def fused_nll(h, w, labels, block_rows: int = 128, block_v: int = 512,
-              block_d: int = 512, interpret: bool = True):
+              block_d: int = 512):
     """Mean masked NLL over (T, D) hidden states against a (D, V) head."""
     lse, gold = kernel_mod.fused_ce_fwd(
         h, w, labels, block_rows=block_rows, block_v=block_v,
-        block_d=block_d, interpret=interpret)
+        block_d=block_d)
     mask = (labels >= 0).astype(jnp.float32)
     return jnp.sum((lse - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def _fwd(h, w, labels, block_rows, block_v, block_d, interpret):
+def _fwd(h, w, labels, block_rows, block_v, block_d):
     lse, gold = kernel_mod.fused_ce_fwd(
         h, w, labels, block_rows=block_rows, block_v=block_v,
-        block_d=block_d, interpret=interpret)
+        block_d=block_d)
     mask = (labels >= 0).astype(jnp.float32)
     denom = jnp.maximum(jnp.sum(mask), 1.0)
     nll = jnp.sum((lse - gold) * mask) / denom
     return nll, (h, w, labels, lse, mask, denom)
 
 
-def _bwd(block_rows, block_v, block_d, interpret, res, g):
+def _bwd(block_rows, block_v, block_d, res, g):
     """d nll / dh = (softmax - onehot) W^T * mask / denom, computed in
     V-chunks against the saved logsumexp — O(T*D + chunk) memory."""
     h, w, labels, lse, mask, denom = res
@@ -87,29 +87,29 @@ fused_nll.defvjp(_fwd, _bwd)
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def fused_gold_logp(h, w, labels, block_rows: int = 128, block_v: int = 512,
-                    block_d: int = 512, interpret: bool = True):
+                    block_d: int = 512):
     """Per-token ``log_softmax(h @ w)[t, labels[t]]`` (T,) f32; negative
     labels wrap python-style, matching ``jnp.take_along_axis``."""
     v = w.shape[1]
     wrapped = jnp.where(labels < 0, labels + v, labels).astype(jnp.int32)
     lse, gold = kernel_mod.fused_ce_fwd(
         h, w, wrapped, block_rows=block_rows, block_v=block_v,
-        block_d=block_d, interpret=interpret)
+        block_d=block_d)
     return gold - lse
 
 
-def _glp_fwd(h, w, labels, block_rows, block_v, block_d, interpret):
+def _glp_fwd(h, w, labels, block_rows, block_v, block_d):
     v = w.shape[1]
     wrapped = jnp.where(labels < 0, labels + v, labels).astype(jnp.int32)
     lse, gold = kernel_mod.fused_ce_fwd(
         h, w, wrapped, block_rows=block_rows, block_v=block_v,
-        block_d=block_d, interpret=interpret)
+        block_d=block_d)
     return gold - lse, (h, w, wrapped, lse)
 
 
-def _glp_bwd(block_rows, block_v, block_d, interpret, res, g):
+def _glp_bwd(block_rows, block_v, block_d, res, g):
     """d logp / dlogits = onehot - softmax, scaled per token by ``g`` —
     computed in V-chunks against the saved logsumexp, O(T*D + chunk)."""
     h, w, wrapped, lse = res

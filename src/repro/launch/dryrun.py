@@ -47,14 +47,6 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 
-def _cost_dict(cost) -> dict:
-    """Normalize Compiled.cost_analysis() across jax versions (older
-    releases return a one-element list of dicts)."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def parse_collective_bytes(hlo_text: str) -> dict[str, int]:
     """Sum result-buffer bytes per collective kind.  Result size is the
     per-device traffic proxy: all-reduce result == operand; all-gather
@@ -130,7 +122,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = parse_collective_bytes(compiled.as_text())
 
     # ---- trip-count correction: XLA counts scan bodies once; add
@@ -150,7 +142,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                 out_shardings=plow.out_shardings,
                 donate_argnums=plow.donate_argnums,
             ).lower(*plow.args).compile()
-        pcost = _cost_dict(pc.cost_analysis())
+        pcost = pc.cost_analysis()
         pcoll = parse_collective_bytes(pc.as_text())
         parts_out[pname] = {
             "flops": float(pcost.get("flops", 0.0)),

@@ -74,6 +74,7 @@ from repro.configs.base import ModelConfig, RuntimeConfig
 from repro.core import partition as partition_mod
 from repro.core import verify
 from repro.core.scheduler import ServeStats
+from repro.distributed import collectives
 from repro.kernels.attention import ops as attn_ops
 from repro.kernels.fused_stack.ops import DispatchStats
 from repro.models import lm
@@ -332,22 +333,6 @@ class PrefixCache:
         self._order.clear()
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat shard_map (graduated from jax.experimental; the
-    replication-checker kwarg was renamed along the way).  The checker is
-    off: pallas calls inside the region have no replication rule."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
-
-
 def _mixed_step_fn(cfg: ModelConfig, rt: RuntimeConfig):
     """The raw mixed prefill/decode step for (cfg, rt) — what
     :func:`_jitted_mixed_step` jits directly and what a mesh-backed Engine
@@ -559,8 +544,8 @@ class Engine:
         in_specs += [plan.operand_spec(2), vec, vec, vec, vec, P(None)]
         raw = _mixed_step_fn(cfg_local, rt_local)
         return jax.jit(
-            _shard_map(raw, mesh, in_specs=tuple(in_specs),
-                       out_specs=(vec, cspecs)),
+            collectives.shard_map(raw, mesh, in_specs=tuple(in_specs),
+                                  out_specs=(vec, cspecs)),
             donate_argnums=(1,))
 
     def report(self) -> dict:

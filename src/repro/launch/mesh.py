@@ -17,15 +17,10 @@ _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
 def _make_mesh(shape, axes, devices=None) -> Mesh:
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist on
-    # newer jax; older releases default to Auto axes anyway.
     if devices is not None:
         return Mesh(np.asarray(devices).reshape(shape), axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

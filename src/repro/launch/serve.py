@@ -40,7 +40,7 @@ from repro.configs import get_config
 from repro.configs.base import RuntimeConfig
 from repro.core.scheduler import ServeStats
 from repro.kernels.fused_stack.ops import DispatchStats
-from repro.launch import engine as engine_mod
+from repro.launch import compile_cache, engine as engine_mod
 from repro.models import lm
 
 STATS = DispatchStats(keys=("prefill", "decode", "decode_slot_steps",
@@ -58,6 +58,9 @@ class ServeConfig:
     max_len: int = 64
     temperature: float = 0.0           # 0 = greedy
     seed: int = 0
+    # (field, value) pairs applied to the model config after ``reduced``,
+    # e.g. (("n_layers", 8),) serves the published widths cut in depth
+    config_overrides: tuple = ()
 
 
 class Server:
@@ -67,13 +70,15 @@ class Server:
         cfg = get_config(sc.arch)
         if sc.reduced:
             cfg = cfg.reduced()
+        if sc.config_overrides:
+            cfg = dataclasses.replace(cfg, **dict(sc.config_overrides))
         if not cfg.supports_decode:
             raise ValueError(f"{sc.arch} is encoder-only; no decode path")
         if cfg.frontend == "vision_patches":
             cfg = dataclasses.replace(cfg, frontend=None, n_prefix_tokens=0)
         self.cfg = cfg
         self.sc = sc
-        self.rt = RuntimeConfig(mode=sc.mode, interpret=True)
+        self.rt = RuntimeConfig(mode=sc.mode)
         self.params, _ = lm.init(jax.random.PRNGKey(sc.seed), cfg)
         self.last_stats: ServeStats | None = None
         self.last_dispatch: dict[str, int] | None = None
@@ -266,6 +271,7 @@ def main(argv=None) -> int:
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     sc = ServeConfig(arch=args.arch, mode=args.mode, batch=args.batch,
                      prompt_len=args.prompt_len, new_tokens=args.new_tokens,

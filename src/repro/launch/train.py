@@ -31,7 +31,7 @@ from repro.distributed import compression
 from repro.distributed import data_parallel as dp_mod
 from repro.distributed import fault_tolerance as ft
 from repro.distributed import sharding as shd
-from repro.launch import mesh as mesh_mod
+from repro.launch import compile_cache, mesh as mesh_mod
 from repro.launch import steps as steps_mod
 from repro.models import lm
 from repro.optim import adamw
@@ -138,7 +138,7 @@ def build_trainer(tc: TrainerConfig) -> Trainer:
 
     mesh = (mesh_mod.make_test_mesh(tc.mesh_devices) if tc.mesh_devices
             else mesh_mod.make_host_mesh())
-    rt = RuntimeConfig(mode=tc.mode, remat=tc.remat, interpret=True)
+    rt = RuntimeConfig(mode=tc.mode, remat=tc.remat)
     rules = shd.ShardingRules()
 
     params, axes = lm.init(jax.random.PRNGKey(tc.seed), cfg)
@@ -254,6 +254,7 @@ def main(argv=None) -> int:
                          "(host platform devices)")
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     tc = TrainerConfig(arch=args.arch, shape=args.shape, steps=args.steps,
                        mode=args.mode, remat=args.remat,

@@ -186,7 +186,7 @@ def apply(params, x: jnp.ndarray, cfg: ModelConfig, rt: RuntimeConfig,
         o = q + 0.0 * jnp.mean(v) + 0.0 * jnp.mean(k)
     elif rt.mode == "brainslug":
         o = attn_ops.flash_attention(q, k, v, causal, rt.attn_block_q,
-                                     rt.attn_block_k, rt.interpret)
+                                     rt.attn_block_k)
     elif rt.mode == "barrier":
         o = _full_attention(q, k, v, causal, barrier=True)
     elif s > FULL_SCORE_MAX_SEQ:
@@ -291,7 +291,7 @@ def _decode_paged(params, x_t: jnp.ndarray, cache: PagedKVCache,
         attn_ops.STATS.record("paged_decode_pallas")
         o = attn_ops.paged_flash_decode(
             q, k_pool.astype(q.dtype), v_pool.astype(q.dtype), table,
-            lengths, interpret=rt.interpret)
+            lengths)
     else:
         attn_ops.STATS.record("paged_decode_ref")
         o = attn_ref.paged_decode_ref(
@@ -351,8 +351,7 @@ def decode(params, x_t: jnp.ndarray, cache, cfg: ModelConfig,
     if rt.mode == "brainslug":
         attn_ops.STATS.record("decode_pallas")
         o = attn_ops.flash_decode(q, k.astype(q.dtype), v.astype(q.dtype),
-                                  lengths, block_k=rt.decode_block_k,
-                                  interpret=rt.interpret)
+                                  lengths, block_k=rt.decode_block_k)
     else:
         attn_ops.STATS.record("decode_ref")
         o = attn_ref.decode_ref(q, k.astype(q.dtype), v.astype(q.dtype),

@@ -42,12 +42,10 @@ def apply(params, x: jnp.ndarray, cfg: ModelConfig, rt: RuntimeConfig
     if "wg" in params:
         gate = jnp.einsum("bsd,df->bsf", x, params["wg"])
         up = jnp.einsum("bsd,df->bsf", x, params["wu"])
-        h = stacks.glu(gate, up, act=cfg.act, mode=rt.mode,
-                       interpret=rt.interpret)
+        h = stacks.glu(gate, up, act=cfg.act, mode=rt.mode)
         return jnp.einsum("bsf,fd->bsd", h, params["wd"])
     h = jnp.einsum("bsd,df->bsf", x, params["wu"]) + params["bu"]
-    h = stacks.activation(h, act=cfg.act, mode=rt.mode,
-                          interpret=rt.interpret)
+    h = stacks.activation(h, act=cfg.act, mode=rt.mode)
     return jnp.einsum("bsf,fd->bsd", h, params["wd"]) + params["bd"]
 
 

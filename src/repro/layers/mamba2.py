@@ -77,7 +77,7 @@ def _gated_norm_program(eps: float) -> ir.StackProgram:
 
 def _ssd_dispatch(xs, dt, A, B, C, D, rt: RuntimeConfig):
     if rt.mode == "brainslug":
-        return ssd_ops.ssd(xs, dt, A, B, C, D, rt.ssd_chunk, rt.interpret)
+        return ssd_ops.ssd(xs, dt, A, B, C, D, rt.ssd_chunk)
     return ssd_chunked.ssd_chunked(xs, dt, A, B, C, D, chunk=rt.ssd_chunk)
 
 
@@ -104,8 +104,7 @@ def apply(params, x: jnp.ndarray, cfg: ModelConfig, rt: RuntimeConfig
 
     out = fused_ops.fused_stack_apply(
         _gated_norm_program(1e-6), {"y": y, "z": z},
-        {"scale": params["norm_scale"]}, mode=rt.mode,
-        interpret=rt.interpret)["o"]
+        {"scale": params["norm_scale"]}, mode=rt.mode)["o"]
     return jnp.einsum("bse,ed->bsd", out, params["wo"])
 
 
@@ -170,8 +169,7 @@ def decode(params, x_t: jnp.ndarray, cache: MambaCache, cfg: ModelConfig,
 
     out = fused_ops.fused_stack_apply(
         _gated_norm_program(1e-6), {"y": y[:, None], "z": z[:, None]},
-        {"scale": params["norm_scale"]}, mode=rt.mode,
-        interpret=rt.interpret)["o"]
+        {"scale": params["norm_scale"]}, mode=rt.mode)["o"]
     new_conv = window[:, 1:].astype(cache.conv.dtype)
     if active is not None:
         new_conv = jnp.where(active[:, None, None], new_conv, cache.conv)

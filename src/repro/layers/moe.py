@@ -140,7 +140,7 @@ def _apply_dispatch(params, x: jnp.ndarray, cfg: ModelConfig,
     # ---- expert FFN (gated) ---------------------------------------------------
     ge = jnp.einsum("gecd,edf->gecf", xe, params["wg"])
     ue = jnp.einsum("gecd,edf->gecf", xe, params["wu"])
-    he = stacks.glu(ge, ue, act=cfg.act, mode=rt.mode, interpret=rt.interpret)
+    he = stacks.glu(ge, ue, act=cfg.act, mode=rt.mode)
     ye = _constrain(jnp.einsum("gecf,efd->gecd", he, params["wd"]), rt)
 
     # ---- weighted combine back to tokens -------------------------------------

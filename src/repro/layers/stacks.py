@@ -73,46 +73,43 @@ def act_program(act: str) -> ir.StackProgram:
 
 def add_norm(x: jnp.ndarray, residual: jnp.ndarray, scale: jnp.ndarray,
              bias: jnp.ndarray | None, *, norm: str = "rms",
-             eps: float = 1e-6, mode: str = "xla", interpret: bool = True):
+             eps: float = 1e-6, mode: str = "xla"):
     """Fused residual add + norm.  Returns (normed, new_residual)."""
     if mode == "brainslug" and norm == "rms" and bias is None:
-        y, h = rms_ops.rmsnorm(x, scale, residual, eps, 256, interpret)
+        y, h = rms_ops.rmsnorm(x, scale, residual, eps, 256)
         return y, h
     prog = addnorm_program(norm, eps, bias is not None)
     params = {"scale": scale}
     if bias is not None:
         params["bias"] = bias
     out = fused_ops.fused_stack_apply(
-        prog, {"x": x, "res": residual}, params, mode=mode,
-        interpret=interpret)
+        prog, {"x": x, "res": residual}, params, mode=mode)
     return out["y"], out["h"]
 
 
 def apply_norm(x: jnp.ndarray, scale: jnp.ndarray,
                bias: jnp.ndarray | None = None, *, norm: str = "rms",
-               eps: float = 1e-6, mode: str = "xla",
-               interpret: bool = True) -> jnp.ndarray:
+               eps: float = 1e-6, mode: str = "xla") -> jnp.ndarray:
     if mode == "brainslug" and norm == "rms" and bias is None:
-        y, _ = rms_ops.rmsnorm(x, scale, None, eps, 256, interpret)
+        y, _ = rms_ops.rmsnorm(x, scale, None, eps, 256)
         return y
     prog = norm_program(norm, eps, bias is not None)
     params = {"scale": scale}
     if bias is not None:
         params["bias"] = bias
-    return fused_ops.fused_stack_apply(prog, {"x": x}, params, mode=mode,
-                                       interpret=interpret)["y"]
+    return fused_ops.fused_stack_apply(prog, {"x": x}, params,
+                                       mode=mode)["y"]
 
 
 def glu(gate: jnp.ndarray, up: jnp.ndarray, *, act: str = "silu",
-        mode: str = "xla", interpret: bool = True) -> jnp.ndarray:
+        mode: str = "xla") -> jnp.ndarray:
     if mode == "brainslug" and act in ("silu", "gelu", "squared_relu"):
-        return swiglu_ops.swiglu(gate, up, act, 256, interpret)
+        return swiglu_ops.swiglu(gate, up, act, 256)
     return fused_ops.fused_stack_apply(
-        glu_program(act), {"gate": gate, "up": up}, {}, mode=mode,
-        interpret=interpret)["y"]
+        glu_program(act), {"gate": gate, "up": up}, {}, mode=mode)["y"]
 
 
-def activation(x: jnp.ndarray, *, act: str, mode: str = "xla",
-               interpret: bool = True) -> jnp.ndarray:
+def activation(x: jnp.ndarray, *, act: str, mode: str = "xla"
+               ) -> jnp.ndarray:
     return fused_ops.fused_stack_apply(
-        act_program(act), {"x": x}, {}, mode=mode, interpret=interpret)["y"]
+        act_program(act), {"x": x}, {}, mode=mode)["y"]

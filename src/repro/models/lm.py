@@ -130,7 +130,7 @@ def init(key, cfg: ModelConfig) -> tuple[Any, Any]:
 def _apply_sub(kind: str, p, carry, cfg: ModelConfig, rt: RuntimeConfig,
                shared_params=None):
     resid, pending, aux = carry
-    norm_kw = dict(norm=cfg.norm, mode=rt.mode, interpret=rt.interpret)
+    norm_kw = dict(norm=cfg.norm, mode=rt.mode)
     if kind == "mamba":
         h1, resid = stacks.add_norm(pending, resid, p["norm1"]["scale"],
                                     p["norm1"].get("bias"), **norm_kw)
@@ -202,7 +202,7 @@ def hidden(params, batch: dict, cfg: ModelConfig, rt: RuntimeConfig
 
     h = stacks.apply_norm(h, params["final_norm"]["scale"],
                           params["final_norm"].get("bias"), norm=cfg.norm,
-                          mode=rt.mode, interpret=rt.interpret)
+                          mode=rt.mode)
     return h, aux
 
 
@@ -272,7 +272,7 @@ def loss_fn(params, batch: dict, cfg: ModelConfig, rt: RuntimeConfig
         from repro.kernels.vocab_ce import ops as ce_ops
         nll = ce_ops.fused_nll(
             h.reshape(-1, h.shape[-1]), params["out_head"],
-            labels.reshape(-1), 128, 512, 512, rt.interpret)
+            labels.reshape(-1), 128, 512, 512)
     else:
         nll = _nll_from_hidden(params, h, labels, cfg, rt.fused_loss_chunk,
                                unroll=rt.loss_unroll)
@@ -437,7 +437,7 @@ def copy_blocks(cache: dict, src: jnp.ndarray, dst: jnp.ndarray) -> dict:
 def _decode_sub(kind: str, p, cache, carry, cfg, rt, shared_params=None,
                 active=None, block_table=None):
     resid, pending = carry
-    norm_kw = dict(norm=cfg.norm, mode=rt.mode, interpret=rt.interpret)
+    norm_kw = dict(norm=cfg.norm, mode=rt.mode)
     if kind == "mamba":
         h1, resid = stacks.add_norm(pending, resid, p["norm1"]["scale"],
                                     p["norm1"].get("bias"), **norm_kw)
@@ -644,5 +644,5 @@ def decode_step(params, cache: dict, tokens_t: jnp.ndarray,
     h = resid + pending
     h = stacks.apply_norm(h, params["final_norm"]["scale"],
                           params["final_norm"].get("bias"), norm=cfg.norm,
-                          mode=rt.mode, interpret=rt.interpret)
+                          mode=rt.mode)
     return _logits(params, h, cfg), new_cache

@@ -19,36 +19,15 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 jax.devices()
 
 # Keep hypothesis deadlines off: jit compilation makes first calls slow.
-# hypothesis is optional (test extra): without it, property tests auto-skip.
+# hypothesis is optional (test extra): without it, test_properties.py skips.
 try:
     from hypothesis import settings  # noqa: E402
 
     settings.register_profile("repro", deadline=None, max_examples=25,
                               derandomize=True)
     settings.load_profile("repro")
-    HAVE_HYPOTHESIS = True
 except ModuleNotFoundError:
-    HAVE_HYPOTHESIS = False
-
-def pytest_ignore_collect(collection_path, config):
-    """Without hypothesis, skip the test modules that import it at module
-    scope (property tests) instead of failing the whole collection."""
-    del config
-    if HAVE_HYPOTHESIS:
-        return None
-    path = str(collection_path)
-    if not path.endswith(".py"):
-        return None
-    try:
-        with open(path, "r", encoding="utf-8") as fh:
-            src = fh.read()
-    except OSError:
-        return None
-    for line in src.splitlines():
-        ls = line.strip()
-        if ls.startswith(("import hypothesis", "from hypothesis")):
-            return True
-    return None
+    pass
 
 
 @pytest.fixture(scope="session")

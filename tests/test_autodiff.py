@@ -191,8 +191,7 @@ class TestRowsBwdKernel:
         gout = {"o": _randn(rng, shape)}
 
         dins, dps = rows_bwd.fused_rows_bwd_call(prog, inputs, params, gout,
-                                                 tile_rows=tile,
-                                                 interpret=True)
+                                                 tile_rows=tile)
         env = _forward_env(prog, inputs, params)
         want_din, want_dp = autodiff.program_vjp(prog, env, params, gout)
         for n in prog.inputs:
@@ -377,7 +376,7 @@ class TestExecutableReuse:
         plan = collapse.collapse(prog, {"g": (8, 64), "u": (8, 64)},
                                  resource.TPU_V5E, itemsize=4,
                                  differentiable=True)
-        codegen.compile_plan(plan, mode="brainslug", interpret=True)
+        codegen.compile_plan(plan, mode="brainslug")
         assert len(fs_ops._EXEC_CACHE) == 1
         exe = next(iter(fs_ops._EXEC_CACHE.values()))
         assert exe.generated_bwd

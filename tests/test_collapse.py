@@ -1,64 +1,17 @@
-"""Property + unit tests for the Collapser (paper Listing 1) and the
-resource model — the invariants the paper's algorithm must satisfy."""
+"""Unit tests for the Collapser (paper Listing 1) and the
+resource model — the invariants the paper's algorithm must satisfy (the
+randomized properties are in ``test_properties.py``)."""
 from __future__ import annotations
 
 import dataclasses
 
 import pytest
-from hypothesis import given, strategies as st
 
 from repro.core import collapse, ir, resource
 from repro.models import cnn
 
 
-# ---------------------------------------------------------------------------
-# Random nhwc-program generator (element-wise + pooling chains).
-# ---------------------------------------------------------------------------
-
-@st.composite
-def nhwc_programs(draw):
-    n_ops = draw(st.integers(1, 12))
-    ops = []
-    v = "x"
-    for i in range(n_ops):
-        kind = draw(st.sampled_from(["relu", "pool", "affine"]))
-        if kind == "relu":
-            ops.append(ir.OpNode(ir.OpKind.EW_UNARY, f"op{i}", (v,),
-                                 f"v{i}", fn="relu"))
-        elif kind == "affine":
-            ops.append(ir.OpNode(ir.OpKind.AFFINE, f"op{i}", (v,), f"v{i}",
-                                 params=(f"s{i}", f"b{i}")))
-        else:
-            k = draw(st.sampled_from([2, 3]))
-            s = draw(st.sampled_from([1, 2]))
-            ops.append(ir.OpNode(
-                ir.OpKind.POOL2D, f"op{i}", (v,), f"v{i}",
-                fn=draw(st.sampled_from(["max", "avg"])),
-                attrs={"window": (k, k), "stride": (s, s),
-                       "padding": (k // 2, k // 2)}))
-        v = f"v{i}"
-    return ir.StackProgram(name="rand", inputs=("x",), outputs=(v,),
-                           ops=tuple(ops), layout="nhwc")
-
-
 class TestBuildSteps:
-    @given(prog=nhwc_programs())
-    def test_step_invariants(self, prog):
-        steps = collapse.build_steps(prog)
-        # 1. every op appears exactly once, in order
-        flat = [op for s in steps for op in s.ops]
-        assert flat == list(prog.ops)
-        # 2. at most one non-element-wise op per step (paper rule)
-        for s in steps:
-            non_ew = [op for op in s.ops if not op.is_elementwise]
-            assert len(non_ew) <= 1
-        # 3. steps are maximal: two consecutive steps cannot merge without
-        #    violating rule 2
-        for a, b in zip(steps, steps[1:]):
-            merged_non_ew = [op for op in a.ops + b.ops
-                             if not op.is_elementwise]
-            assert len(merged_non_ew) >= 2
-
     def test_pure_elementwise_is_one_step(self):
         ops = tuple(ir.OpNode(ir.OpKind.EW_UNARY, f"r{i}",
                               ("x" if i == 0 else f"v{i-1}",), f"v{i}",
@@ -69,33 +22,6 @@ class TestBuildSteps:
 
 
 class TestCollapseInvariants:
-    @given(prog=nhwc_programs(), budget_kb=st.sampled_from([4, 16, 64, 1024]))
-    def test_sequences_partition_and_fit(self, prog, budget_kb):
-        device = resource.DeviceSpec(name="t", vmem_bytes=budget_kb * 1024,
-                                     vmem_budget_fraction=1.0)
-        shape = (1, 32, 32, 8)
-        try:
-            plan = collapse.collapse(prog, {"x": shape}, device, itemsize=4)
-        except resource.ResourceError:
-            return  # single step legitimately too big for a tiny budget
-        # 1. sequences partition the steps in order
-        flat = [op for seq in plan.sequences for op in seq.ops]
-        assert flat == list(prog.ops)
-        # 2. each sequence's working set fits the budget
-        for seq in plan.sequences:
-            fps = resource.sequence_footprint(
-                [s.ops for s in seq.steps], seq.tile_out_h, seq.tile_out_w,
-                shape[-1], 4, device)
-            assert resource.sequence_bytes(fps) <= device.resource_limit
-
-    @given(prog=nhwc_programs())
-    def test_max_steps_knob(self, prog):
-        plan = collapse.collapse(prog, {"x": (1, 32, 32, 8)},
-                                 resource.TPU_V5E, itemsize=4,
-                                 max_steps_per_sequence=1)
-        for seq in plan.sequences:
-            assert len(seq.steps) == 1
-
     def test_smaller_budget_no_fewer_sequences(self):
         graph, _ = cnn.block_net(8, channels=32)
         prog = ir.StackProgram(name="s", inputs=("x",),

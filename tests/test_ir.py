@@ -1,11 +1,11 @@
-"""Unit + property tests for the BrainSlug op IR."""
+"""Unit tests for the BrainSlug op IR (randomized properties: see
+``test_properties.py``)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, strategies as st
 
 from repro.core import ir
 
@@ -115,17 +115,6 @@ class TestInterpreter:
 
 
 class TestShapes:
-    @given(extent=st.integers(1, 64), k=st.integers(1, 5),
-           s=st.integers(1, 4), p=st.integers(0, 3))
-    def test_pool_extent_roundtrip(self, extent, k, s, p):
-        """pool_in_extent is the least input size producing that output."""
-        out = ir.pool_out_extent(extent, k, s, p)
-        if out < 1:
-            return
-        need = ir.pool_in_extent(out, k, s)
-        # an input of size `need` (already padded) yields exactly `out`
-        assert ir.pool_out_extent(need, k, s, 0) == out
-
     def test_infer_shapes_pool_chain(self):
         ops = (
             ir.OpNode(ir.OpKind.POOL2D, "p0", ("x",), "a", fn="max",

@@ -1,14 +1,17 @@
-"""jaxpr pattern-table pinning for jax 0.4.37.
+"""jaxpr pattern-table pinning for the installed jax (0.9.0).
 
 The tracer's recognition tables (repro.core.trace) encode structural
 assumptions about how this jax version lowers the standard activations:
 
 * ``jax.nn.gelu`` inlines (the tanh polynomial appears as plain eqns — the
   elementwise-chain prober finds it; there is no call boundary),
-* ``jax.nn.relu`` stages as a ``custom_jvp_call`` (possibly wrapped in a
-  ``pjit``) — the call-boundary behavioral prober handles it,
+* ``jax.nn.relu`` stages as a ``custom_jvp_call`` whose ``call_jaxpr``
+  wraps a ``jit`` of ``max(x, 0)`` — the call-boundary behavioral prober
+  handles it,
 * ``jax.nn.softmax`` inlines with a ``stop_gradient`` fence on its row max
-  — the structural softmax matcher must hop exactly that fence.
+  (``reduce_max`` -> ``max(-inf, .)`` -> ``broadcast_in_dim`` ->
+  ``stop_gradient``) — the structural softmax matcher must hop exactly
+  that fence.
 
 A jax upgrade that changes any of these would silently drop tracer
 coverage to OPAQUE (correct output, no acceleration).  These tests stage
@@ -83,12 +86,9 @@ class TestStagingAssumptions:
             assert prim in names, (
                 f"jax.nn.softmax lowering lost the {prim!r} step "
                 f"(got {names}); re-check trace._try_softmax")
-        # the row-max fence is what 0.4.37 stages (the supported floor,
-        # 0.4.35, predates the current spelling — only pin it from here up)
-        if tuple(int(p) for p in jax.__version__.split(".")[:3]) >= (0, 4, 36):
-            assert "stop_gradient" in names, (
-                "jax.nn.softmax lost its row-max stop_gradient fence; "
-                "re-check trace._try_softmax's hop_stop_gradient walk")
+        assert "stop_gradient" in names, (
+            "jax.nn.softmax lost its row-max stop_gradient fence; "
+            "re-check trace._try_softmax's hop_stop_gradient walk")
 
     def test_silu_stages_as_probeable_call_or_chain(self):
         """silu is either a recognized call boundary or an inline
@@ -107,8 +107,7 @@ class TestStagingAssumptions:
                 lambda v: jax.nn.log_softmax(v, axis=-1))(_x()).jaxpr,
             recursive=True)
         assert "reduce_sum" in names and "log" in names
-        if tuple(int(p) for p in jax.__version__.split(".")[:3]) >= (0, 4, 36):
-            assert "stop_gradient" in names
+        assert "stop_gradient" in names
 
 
 class TestLiftingPinned:

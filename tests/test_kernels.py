@@ -48,7 +48,7 @@ class TestRMSNorm:
         x = _randn(rng, shape, dtype)
         res = _randn(rng, shape, dtype) if with_residual else None
         scale = _randn(rng, shape[-1:], dtype)
-        y, h = rms_ops.rmsnorm(x, scale, res, 1e-6, 64, True)
+        y, h = rms_ops.rmsnorm(x, scale, res, 1e-6, 64)
         yr, hr = rms_ref.rmsnorm_ref(x, scale, res, eps=1e-6)
         _close(y, yr, dtype)
         _close(h, hr, dtype)
@@ -59,7 +59,7 @@ class TestRMSNorm:
         scale = _randn(rng, (64,), jnp.float32)
 
         def f_kernel(x, s, r):
-            y, h = rms_ops.rmsnorm(x, s, r, 1e-6, 8, True)
+            y, h = rms_ops.rmsnorm(x, s, r, 1e-6, 8)
             return jnp.sum(y * 1.3 + h * 0.7)
 
         def f_ref(x, s, r):
@@ -83,14 +83,13 @@ class TestSwiGLU:
     def test_fwd_matches_ref(self, rng, dtype, act, shape):
         g = _randn(rng, shape, dtype)
         u = _randn(rng, shape, dtype)
-        y = sw_ops.swiglu(g, u, act, 64, True)
+        y = sw_ops.swiglu(g, u, act, 64)
         _close(y, sw_ref.swiglu_ref(g, u, act=act), dtype)
 
     def test_grads_match_ref(self, rng):
         g = _randn(rng, (6, 96), jnp.float32)
         u = _randn(rng, (6, 96), jnp.float32)
-        gk = jax.grad(lambda a, b: jnp.sum(sw_ops.swiglu(a, b, "silu", 8,
-                                                         True)),
+        gk = jax.grad(lambda a, b: jnp.sum(sw_ops.swiglu(a, b, "silu", 8)),
                       argnums=(0, 1))(g, u)
         gr = jax.grad(lambda a, b: jnp.sum(sw_ref.swiglu_ref(a, b)),
                       argnums=(0, 1))(g, u)
@@ -113,7 +112,7 @@ class TestFlashAttention:
         q = _randn(rng, (b, h, sq, d), dtype)
         k = _randn(rng, (b, g, sq, d), dtype)
         v = _randn(rng, (b, g, sq, d), dtype)
-        o = attn_ops.flash_attention(q, k, v, causal, block, block, True)
+        o = attn_ops.flash_attention(q, k, v, causal, block, block)
         oref = attn_ref.attention_ref(q, k, v, causal=causal)
         _close(o, oref, dtype)
 
@@ -123,7 +122,7 @@ class TestFlashAttention:
         k = _randn(rng, (b, g, s, d), jnp.float32)
         v = _randn(rng, (b, g, s, d), jnp.float32)
         gk = jax.grad(lambda *a: jnp.sum(
-            attn_ops.flash_attention(*a, True, 16, 16, True)),
+            attn_ops.flash_attention(*a, True, 16, 16)),
             argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(lambda *a: jnp.sum(
             attn_ref.attention_ref(*a, causal=True)),
@@ -139,8 +138,7 @@ class TestFlashAttention:
         k = _randn(rng, (b, g, s, d), dtype)
         v = _randn(rng, (b, g, s, d), dtype)
         lengths = jnp.asarray(rng.integers(1, s + 1, (b,)), jnp.int32)
-        o = attn_ops.flash_decode(q, k, v, lengths, block_k=block_k,
-                                  interpret=True)
+        o = attn_ops.flash_decode(q, k, v, lengths, block_k=block_k)
         oref = attn_ref.decode_ref(q, k, v, lengths)
         _close(o, oref, dtype)
 
@@ -151,10 +149,10 @@ class TestFlashAttention:
         k = _randn(rng, (b, g, s, d), jnp.float32)
         v = _randn(rng, (b, g, s, d), jnp.float32)
         lengths = jnp.asarray([40], jnp.int32)
-        o1 = attn_ops.flash_decode(q, k, v, lengths, interpret=True)
+        o1 = attn_ops.flash_decode(q, k, v, lengths)
         k2 = k.at[:, :, 40:].set(99.0)
         v2 = v.at[:, :, 40:].set(-99.0)
-        o2 = attn_ops.flash_decode(q, k2, v2, lengths, interpret=True)
+        o2 = attn_ops.flash_decode(q, k2, v2, lengths)
         _close(o1, o2, jnp.float32)
 
     @pytest.mark.parametrize("block_k", [32, 128])
@@ -168,8 +166,7 @@ class TestFlashAttention:
         k = _randn(rng, (b, g, s, d), jnp.float32)
         v = _randn(rng, (b, g, s, d), jnp.float32)
         lengths = jnp.asarray([0, s // 2], jnp.int32)
-        o = attn_ops.flash_decode(q, k, v, lengths, block_k=block_k,
-                                  interpret=True)
+        o = attn_ops.flash_decode(q, k, v, lengths, block_k=block_k)
         assert bool(jnp.isfinite(o).all())
         np.testing.assert_array_equal(np.asarray(o[0]),
                                       np.zeros_like(np.asarray(o[0])))
@@ -186,8 +183,7 @@ class TestFlashAttention:
         k = _randn(rng, (b, g, s, d), jnp.float32)
         v = _randn(rng, (b, g, s, d), jnp.float32)
         lengths = jnp.full((b,), s, jnp.int32)
-        o = attn_ops.flash_decode(q, k, v, lengths, block_k=32,
-                                  interpret=True)
+        o = attn_ops.flash_decode(q, k, v, lengths, block_k=32)
         oref = attn_ref.attention_ref(q, k, v, causal=False)  # no length op
         _close(o, oref, jnp.float32)
 
@@ -201,8 +197,7 @@ class TestFlashAttention:
         q = _randn(rng, (b, h, 1, d), jnp.float32)
         k = _randn(rng, (b, g, s, d), jnp.float32)
         v = _randn(rng, (b, g, s, d), jnp.float32)
-        o = attn_ops.flash_decode(q, k, v, lengths, block_k=32,
-                                  interpret=True)
+        o = attn_ops.flash_decode(q, k, v, lengths, block_k=32)
         oref = attn_ref.decode_ref(q, k, v, lengths)
         assert bool(jnp.isfinite(o).all())
         _close(o, oref, jnp.float32)
@@ -235,13 +230,13 @@ class TestSSD:
     @pytest.mark.parametrize("s,chunk", [(64, 16), (96, 32)])
     def test_pallas_matches_sequential(self, rng, dtype, s, chunk):
         x, dt, A, B, C, D = _ssd_operands(rng, 2, s, 3, 16, 8, dtype)
-        y = ssd_ops.ssd(x, dt, A, B, C, D, chunk, True)
+        y = ssd_ops.ssd(x, dt, A, B, C, D, chunk)
         yr = ssd_ref.ssd_ref(x, dt, A, B, C, D)
         _close(y, yr, dtype)
 
     def test_pallas_grads_match_ref(self, rng):
         x, dt, A, B, C, D = _ssd_operands(rng, 1, 32, 2, 8, 4, jnp.float32)
-        gk = jax.grad(lambda *a: jnp.sum(ssd_ops.ssd(*a, 16, True)),
+        gk = jax.grad(lambda *a: jnp.sum(ssd_ops.ssd(*a, 16)),
                       argnums=(0, 1, 2, 3, 4))(x, dt, A, B, C, D)
         gr = jax.grad(lambda *a: jnp.sum(ssd_ref.ssd_ref(*a)),
                       argnums=(0, 1, 2, 3, 4))(x, dt, A, B, C, D)
@@ -290,8 +285,7 @@ class TestFusedRows:
         u = _randn(rng, shape, dtype)
         scale = _randn(rng, shape[-1:], dtype)
         out = fs_rows.fused_rows_call(prog, {"g": g, "u": u},
-                                      {"scale": scale}, tile_rows=tile,
-                                      interpret=True)
+                                      {"scale": scale}, tile_rows=tile)
         want = fs_ref.fused_stack_ref(prog, {"g": g, "u": u},
                                       {"scale": scale})
         _close(out["o"], want["o"], dtype)
@@ -359,7 +353,7 @@ class TestFusedNHWC:
             params[f"s{i}"] = 1.0 + 0.1 * _randn(rng, (8,), dtype)
             params[f"o{i}"] = 0.1 * _randn(rng, (8,), dtype)
         y = fs_nhwc.fused_nhwc_call(prog, x, params, tile_out_h=tile,
-                                    tile_out_w=tile, interpret=True)
+                                    tile_out_w=tile)
         want = fs_ref.fused_stack_ref(prog, {"x": x}, params)
         _close(y, want[prog.outputs[0]], dtype)
 
@@ -374,7 +368,7 @@ class TestFusedNHWC:
         params = {f"s{i}": jnp.ones((8,)) for i in range(2)}
         params.update({f"o{i}": jnp.zeros((8,)) for i in range(2)})
         y = fs_nhwc.fused_nhwc_call(prog, x, params, tile_out_h=4,
-                                    tile_out_w=4, interpret=True)
+                                    tile_out_w=4)
         want = fs_ref.fused_stack_ref(prog, {"x": x}, params)
         _close(y, want[prog.outputs[0]], jnp.float32)
 
@@ -387,8 +381,7 @@ class TestFusedNHWC:
                            attrs={"window": (3, 3), "stride": (1, 1),
                                   "padding": (1, 1)}),))
         x = jnp.ones((1, 5, 5, 8), jnp.float32)
-        y = fs_nhwc.fused_nhwc_call(prog, x, {}, tile_out_h=4, tile_out_w=4,
-                                    interpret=True)
+        y = fs_nhwc.fused_nhwc_call(prog, x, {}, tile_out_h=4, tile_out_w=4)
         want = fs_ref.fused_stack_ref(prog, {"x": x}, {})["y"]
         _close(y, want, jnp.float32)
         # corner value must be 4/9, not 1 (padding included in the count)

@@ -508,7 +508,7 @@ class TestEntryVjpDeclaration:
         import dataclasses as dc
         base = registry.REGISTRY["swiglu"]
 
-        def fenced_pallas(args, attrs, interpret):
+        def fenced_pallas(args, attrs):
             # forward-correct but gradient-dead without the wrapper
             return jax.lax.stop_gradient(base.ref(args, attrs))
 

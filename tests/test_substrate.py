@@ -7,13 +7,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, strategies as st
 
 from repro.checkpoint import checkpointer as ckpt
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.data import pipeline as data_mod
-from repro.optim import adamw, schedule
+from repro.optim import adamw
 
 
 class TestAdamW:
@@ -66,21 +65,6 @@ class TestAdamW:
         assert p2["w"].dtype == jnp.bfloat16
         assert s2["nu"]["w"].dtype == jnp.float32
 
-    @given(warm=st.integers(1, 50), total=st.integers(60, 500))
-    def test_schedule_properties(self, warm, total):
-        sched = schedule.warmup_cosine(1e-3, warm, total)
-        steps = jnp.asarray([0, warm, total, total * 2])
-        vals = [float(sched(s)) for s in steps]
-        assert vals[0] == 0.0
-        assert vals[1] == pytest.approx(1e-3, rel=1e-4)
-        assert vals[2] == pytest.approx(1e-4, rel=1e-3)   # final_fraction
-        assert vals[3] == pytest.approx(1e-4, rel=1e-3)   # clamped
-        # monotone decay after warmup
-        post = [float(sched(jnp.asarray(s)))
-                for s in range(warm, total, max((total - warm) // 7, 1))]
-        assert all(a >= b - 1e-12 for a, b in zip(post, post[1:]))
-
-
 class TestCheckpoint:
     def _tree(self):
         return {"a": {"w": jnp.arange(6.0).reshape(2, 3)},
@@ -108,7 +92,7 @@ class TestCheckpoint:
         tree = self._tree()
         ckpt.save(str(tmp_path), 0, tree)
         bad = {"a": {"w": jnp.zeros((3, 3))}, "b": tree["b"]}
-        with pytest.raises(ValueError, match="shape mismatch"):
+        with pytest.raises(ckpt.CheckpointError, match="shape mismatch"):
             ckpt.restore(str(tmp_path), 0, bad)
 
     def test_incomplete_marker_rejected(self, tmp_path):
@@ -121,7 +105,7 @@ class TestCheckpoint:
         m["complete"] = False
         with open(man, "w") as f:
             json.dump(m, f)
-        with pytest.raises(IOError, match="incomplete"):
+        with pytest.raises(ckpt.CheckpointError, match="incomplete"):
             ckpt.restore(str(tmp_path), 0, tree)
 
     def test_async_checkpointer(self, tmp_path):

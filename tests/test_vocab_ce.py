@@ -32,8 +32,7 @@ class TestFusedCE:
     def test_fwd_matches_ref(self, rng, dtype, t, d, v, br, bv, bd):
         h, w, labels = _operands(rng, t, d, v, dtype)
         lse, gold = ce_mod.fused_ce_fwd(h, w, labels, block_rows=br,
-                                        block_v=bv, block_d=bd,
-                                        interpret=True)
+                                        block_v=bv, block_d=bd)
         lse_r, gold_r = ce_ref.ce_ref(h, w, labels)
         tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
             else dict(rtol=2e-5, atol=2e-5)
@@ -49,8 +48,7 @@ class TestFusedCE:
         h = h - 0.0
         w = -jnp.abs(w) - 1.0          # all logits strictly negative
         lse, gold = ce_mod.fused_ce_fwd(h, w, labels, block_rows=8,
-                                        block_v=16, block_d=16,
-                                        interpret=True)
+                                        block_v=16, block_d=16)
         lse_r, _ = ce_ref.ce_ref(h, w, labels)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
                                    rtol=1e-5, atol=1e-5)
@@ -59,7 +57,7 @@ class TestFusedCE:
         t, d, v = 24, 32, 96
         h, w, labels = _operands(rng, t, d, v, jnp.float32)
         labels = labels.at[::3].set(-1)        # mask a third
-        nll_k = ce_ops.fused_nll(h, w, labels, 8, 32, 16, True)
+        nll_k = ce_ops.fused_nll(h, w, labels, 8, 32, 16)
         nll_r = ce_ref.nll_ref(h, w, labels)
         np.testing.assert_allclose(float(nll_k), float(nll_r), rtol=1e-5)
 
@@ -68,7 +66,7 @@ class TestFusedCE:
         h, w, labels = _operands(rng, t, d, v, jnp.float32)
         labels = labels.at[0].set(-1)
         gk = jax.grad(lambda h_, w_: ce_ops.fused_nll(h_, w_, labels,
-                                                      8, 16, 16, True),
+                                                      8, 16, 16),
                       argnums=(0, 1))(h, w)
         gr = jax.grad(lambda h_, w_: ce_ref.nll_ref(h_, w_, labels),
                       argnums=(0, 1))(h, w)
@@ -79,4 +77,4 @@ class TestFusedCE:
     def test_fully_masked_is_zero(self, rng):
         h, w, _ = _operands(rng, 8, 16, 32, jnp.float32)
         labels = jnp.full((8,), -1, jnp.int32)
-        assert float(ce_ops.fused_nll(h, w, labels, 8, 16, 16, True)) == 0.0
+        assert float(ce_ops.fused_nll(h, w, labels, 8, 16, 16)) == 0.0
