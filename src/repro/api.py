@@ -39,6 +39,7 @@ from repro.core import autotune as autotune_mod
 from repro.core import registry as registry_mod
 from repro.core import trace as trace_mod
 from repro.core import verify as verify_mod
+from repro.obs import SPANS, span
 
 # Canonical re-exports: the config and report types live with the core
 # implementation; this module is the supported way to reach them.
@@ -101,6 +102,10 @@ class OptimizedFn:
     #: :meth:`__call__` places concrete input leaves on the mesh
     #: batch-sharded, so data never round-trips through one device.
     partitions: Any = None
+    #: Where the ``optimize()`` call spent its host time: count and
+    #: seconds of each ``optimize.*`` / ``trace.*`` span it opened
+    #: (:mod:`repro.obs`), printed by :meth:`explain`.
+    setup_spans: dict[str, dict] = dataclasses.field(default_factory=dict)
 
     def _place_inputs(self, leaves: list) -> list:
         """Shard concrete input leaves over the mesh's "data" axis (a
@@ -196,8 +201,13 @@ class OptimizedFn:
                                         mode=self.config.mode)
 
     def explain(self) -> str:
-        """Human-readable :meth:`report`."""
-        return str(self.report())
+        """Human-readable :meth:`report`, then the set-up split by span."""
+        lines = [str(self.report())]
+        if self.setup_spans:
+            lines.append("optimize() set-up:")
+            lines += [f"  {name:18s} {v['count']:5d}x {v['seconds']:9.3f} s"
+                      for name, v in self.setup_spans.items()]
+        return "\n".join(lines)
 
 
 def optimize(fn: Callable, *example_args: Any,
@@ -210,13 +220,16 @@ def optimize(fn: Callable, *example_args: Any,
     ``optimize`` never rejects a function, it just captures less of it
     (see :meth:`OptimizedFn.report`).
     """
-    tr = trace_mod.trace(fn, *example_args)
+    before = SPANS.snapshot()
+    with span("optimize.trace"):
+        tr = trace_mod.trace(fn, *example_args)
     # registry pass: backbone clusters a depth-first stack can't absorb
     # (attention / rmsnorm / swiglu / vocab-CE) dispatch to the dedicated
     # kernels instead of replaying OPAQUE prim.bind soup
     matches: tuple = ()
     if config.kernel_registry:
-        tr, matches = registry_mod.rewrite(tr, mode=config.mode)
+        with span("optimize.registry"):
+            tr, matches = registry_mod.rewrite(tr, mode=config.mode)
     # every traced output must survive the rewrite, even one produced
     # mid-stack with no in-graph consumer (stack executors only
     # materialize their declared outputs)
@@ -226,19 +239,21 @@ def optimize(fn: Callable, *example_args: Any,
     # inside compile_stacks, between the collapse and codegen stages
     graph_findings: tuple = ()
     if config.verify != "off":
-        graph_findings = tuple(verify_mod.verify_trace(tr))
-        verify_mod.enforce(graph_findings, config.verify,
-                           subject=tr.graph.name)
-    segments = analyzer.analyze(tr.graph, layout="auto", keep=keep)
+        with span("optimize.verify"):
+            graph_findings = tuple(verify_mod.verify_trace(tr))
+            verify_mod.enforce(graph_findings, config.verify,
+                               subject=tr.graph.name)
     # Autotuning (incl. the function-level floor) is disabled under a
     # mesh: timing forced host devices would commit nonsense decisions.
     under_mesh = config.mesh is not None and config.partition != "none"
     tuner = (autotune_mod.Autotuner.from_config(config)
              if config.autotune and not under_mesh else None)
-    executors, plans, dispatches, tuned, findings, parts = \
-        core_api.compile_stacks(
-            segments, tr.shapes, config, param_shapes=tr.param_shapes,
-            dtypes=tr.dtypes, tuner=tuner)
+    with span("optimize.compile"):
+        segments = analyzer.analyze(tr.graph, layout="auto", keep=keep)
+        executors, plans, dispatches, tuned, findings, parts = \
+            core_api.compile_stacks(
+                segments, tr.shapes, config, param_shapes=tr.param_shapes,
+                dtypes=tr.dtypes, tuner=tuner)
     net = OptimizedFn(trace_result=tr, segments=segments,
                       executors=executors, plans=plans, config=config,
                       shapes=dict(tr.shapes),
@@ -248,7 +263,10 @@ def optimize(fn: Callable, *example_args: Any,
                       verify_findings=graph_findings + findings,
                       partitions=parts)
     if tuner is not None:
-        _floor_whole_function(tuner, net, fn, example_args, config)
+        with span("optimize.floor"):
+            _floor_whole_function(tuner, net, fn, example_args, config)
+    net.setup_spans = {k: v for k, v in SPANS.delta(before).items()
+                       if v["count"]}
     return net
 
 

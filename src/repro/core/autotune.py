@@ -54,7 +54,7 @@ from repro.core import codegen
 from repro.core import collapse as collapse_mod
 from repro.core import ir
 from repro.core import registry as registry_mod
-from repro.kernels.fused_stack.ops import DispatchStats
+from repro.obs import DispatchStats
 
 #: On-disk entry format version; a bump invalidates (quarantines) every
 #: older entry on first contact.

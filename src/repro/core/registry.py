@@ -50,7 +50,7 @@ import numpy as np
 from repro.core import ir
 from repro.kernels.attention import ops as attn_ops
 from repro.kernels.attention import ref as attn_ref
-from repro.kernels.fused_stack.ops import DispatchStats
+from repro.obs import DispatchStats
 from repro.kernels.rmsnorm import ops as rms_ops
 from repro.kernels.rmsnorm import ref as rms_ref
 from repro.kernels.swiglu import ops as swiglu_ops

@@ -47,6 +47,7 @@ from jax import core as jcore
 from jax.extend import core as jex
 
 from repro.core import ir
+from repro.obs import span
 
 __all__ = ["trace", "TraceResult"]
 
@@ -178,7 +179,8 @@ def _flatten(closed: jex.ClosedJaxpr, operands: list, ctx: _FlattenCtx
         sub = _inner_closed_jaxpr(eqn)
         ins = [read(v) for v in eqn.invars]
         if sub is not None:
-            hit = _probe_call(sub, ins, eqn, ctx)
+            with span("trace.probe"):
+                hit = _probe_call(sub, ins, eqn, ctx)
             if hit is not None:
                 virtual, fn_name, src = hit
                 out_id = ctx.fresh(eqn.outvars[0].aval)
@@ -758,22 +760,23 @@ class _Builder:
         if self.avals[end_out].dtype != aval.dtype:
             self._failed_probes.add(end)
             return False
-        probes = _probe_batches(aval)
-        ys = []
-        try:
-            for probe in probes:
-                env = {src: probe}
-                for i in idxs:
-                    atom = self.atoms[i]
-                    args = [env[o] if isinstance(o, int)
-                            else jnp.asarray(o.val)
-                            for o in atom.operands]
-                    env[atom.out_ids[0]] = _eval_atom(atom, args)
-                ys.append(env[end_out])
-        except Exception:
-            self._failed_probes.add(end)
-            return False
-        name = _match_unary_values(probes, ys, aval)
+        with span("trace.chain_probe"):
+            probes = _probe_batches(aval)
+            ys = []
+            try:
+                for probe in probes:
+                    env = {src: probe}
+                    for i in idxs:
+                        atom = self.atoms[i]
+                        args = [env[o] if isinstance(o, int)
+                                else jnp.asarray(o.val)
+                                for o in atom.operands]
+                        env[atom.out_ids[0]] = _eval_atom(atom, args)
+                    ys.append(env[end_out])
+            except Exception:
+                self._failed_probes.add(end)
+                return False
+            name = _match_unary_values(probes, ys, aval)
         if name is None or (name == "identity" and len(idxs) == 1):
             self._failed_probes.add(end)
             return False

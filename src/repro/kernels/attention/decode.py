@@ -144,6 +144,7 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
         functools.partial(_paged_kernel, scale, bs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        name="paged_decode_kernel",
         interpret=kernels.pallas_interpret(),
     )(table, lengths.astype(jnp.int32), q, k_pool, v_pool)
 
@@ -182,5 +183,6 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         functools.partial(_kernel, scale, block_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        name="flash_decode_kernel",
         interpret=kernels.pallas_interpret(),
     )(lengths.astype(jnp.int32), q, kp, vp)

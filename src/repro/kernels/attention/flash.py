@@ -109,6 +109,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_attention_kernel",
         interpret=kernels.pallas_interpret(),
     )(qp, kp, vp)
     return out[:, :, :sq, :]

@@ -8,7 +8,7 @@ import jax
 from repro.kernels.attention import decode as decode_mod
 from repro.kernels.attention import flash as flash_mod
 from repro.kernels.attention import ref as ref_mod
-from repro.kernels.fused_stack.ops import DispatchStats
+from repro.obs import DispatchStats
 
 #: Trace-time decode-dispatch counters (same snapshot/delta protocol as
 #: the fused-stack STATS): which decode path a compilation took — the

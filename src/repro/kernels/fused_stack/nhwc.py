@@ -429,6 +429,7 @@ def fused_nhwc_call(program: ir.StackProgram,
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=tile_scratch(program, levels, cp, x.dtype),
+        name="nhwc_fwd_kernel",
         interpret=kernels.pallas_interpret(),
     )
     out = fn(xp, *evals, *pvals)

@@ -258,6 +258,7 @@ def fused_nhwc_bwd_call(program: ir.StackProgram,
         out_shape=tuple(out_shapes),
         scratch_shapes=nhwc.tile_scratch(program, levels, cp, x.dtype,
                                          backward=True),
+        name="nhwc_bwd_kernel",
         interpret=kernels.pallas_interpret(),
     )
     outs = fn(xp, *evals, *pvals, gp)

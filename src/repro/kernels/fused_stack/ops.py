@@ -33,54 +33,9 @@ import jax.numpy as jnp
 
 from repro.core import autodiff, ir, resource
 from repro.kernels.fused_stack import nhwc, nhwc_bwd, ref, rows, rows_bwd
+from repro.obs import DispatchStats
 
 MODES = ("brainslug", "xla", "barrier")
-
-
-class DispatchStats:
-    """Trace-time dispatch counters (the mode stat the acceptance criteria
-    ask for): which path ran — the generated depth-first kernel or the
-    reference-interpreter fallback.  Counts are incremented when the path is
-    *traced*, i.e. once per compilation, which is exactly the "was the
-    generated kernel used" question.
-
-    The instance is a process-global singleton (``STATS``); callers that
-    need isolation take a :meth:`snapshot` first and diff against it
-    (``STATS.delta(before)``) instead of asserting absolute counts —
-    benchmark drivers additionally :meth:`reset` at phase boundaries so
-    counts do not bleed across runs.
-
-    The class is key-set agnostic so other dispatch surfaces can reuse the
-    snapshot/delta protocol: the serving drivers instantiate their own
-    counters (``repro.launch.serve.STATS`` / ``repro.launch.engine.STATS``)
-    with *runtime* dispatch keys — there the counts are per call, not per
-    trace, because "how many decode dispatches did the loop issue" is the
-    question those counters answer."""
-
-    BASE_KEYS = ("fwd_generated", "fwd_reference",
-                 "bwd_generated", "bwd_reference")
-
-    def __init__(self, keys: tuple[str, ...] = BASE_KEYS) -> None:
-        self._keys = tuple(keys)
-        self.reset()
-
-    def reset(self) -> None:
-        self.counts: dict[str, int] = {k: 0 for k in self._keys}
-
-    def record(self, key: str, n: int = 1) -> None:
-        if key not in self.counts:
-            raise KeyError(
-                f"unknown dispatch counter {key!r}; declared: {self._keys}")
-        self.counts[key] += n
-
-    def snapshot(self) -> dict[str, int]:
-        """An immutable copy of the current counts, for later diffing."""
-        return dict(self.counts)
-
-    def delta(self, before: Mapping[str, int]) -> dict[str, int]:
-        """Counts recorded since ``before`` (a :meth:`snapshot`)."""
-        return {k: v - before.get(k, 0) for k, v in self.counts.items()}
-
 
 STATS = DispatchStats()
 

@@ -156,6 +156,7 @@ def fused_rows_call(program: ir.StackProgram,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
+        name="rows_fwd_kernel",
         interpret=kernels.pallas_interpret(),
     )
     outs = fn(*flat, *pvals)

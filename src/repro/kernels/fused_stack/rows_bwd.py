@@ -159,6 +159,7 @@ def fused_rows_bwd_call(program: ir.StackProgram,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(din_shapes + dparam_shapes),
+        name="rows_bwd_kernel",
         interpret=kernels.pallas_interpret(),
     )
     outs = fn(*flat, *pvals, *gflat)

@@ -92,6 +92,7 @@ def rmsnorm_fwd(x: jnp.ndarray,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
+        name="rmsnorm_kernel",
         interpret=kernels.pallas_interpret(),
     )(*operands)
     if not isinstance(outs, (list, tuple)):

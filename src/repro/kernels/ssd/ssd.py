@@ -73,6 +73,7 @@ def ssd_intra_chunk(dtx: jnp.ndarray, a: jnp.ndarray, B: jnp.ndarray,
             jax.ShapeDtypeStruct((b, h, nc, L, p), jnp.float32),
             jax.ShapeDtypeStruct((b, h, nc, n, p), jnp.float32),
         ),
+        name="ssd_chunk_kernel",
         interpret=kernels.pallas_interpret(),
     )(dtx, a, B, C)
     return y, s

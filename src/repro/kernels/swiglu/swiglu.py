@@ -68,6 +68,7 @@ def swiglu_fwd(gate: jnp.ndarray, up: jnp.ndarray, *, act: str = "silu",
         in_specs=[tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows + pad, f), gate.dtype),
+        name="swiglu_kernel",
         interpret=kernels.pallas_interpret(),
     )(gf, uf)
     return y[:rows].reshape(*lead, f)

@@ -121,6 +121,7 @@ def fused_ce_fwd(h: jnp.ndarray, w: jnp.ndarray, labels: jnp.ndarray,
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
+        name="vocab_ce_kernel",
         interpret=kernels.pallas_interpret(),
     )(hp, wp, labp)
     return lse[:t, 0], gold[:t, 0]

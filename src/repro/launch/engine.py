@@ -53,9 +53,14 @@ scheduler tick commits tokens, so callers observe generations in commit
 order instead of waiting for the run to drain.
 
 Dispatch accounting lives in two places: ``STATS`` (a runtime-keyed
-:class:`~repro.kernels.fused_stack.ops.DispatchStats`, snapshot/delta
-protocol) and the per-run :class:`~repro.core.scheduler.ServeStats`
-returned via :attr:`Engine.last_stats`.
+:class:`~repro.obs.DispatchStats`, snapshot/delta protocol) and the
+per-run :class:`~repro.core.scheduler.ServeStats` returned via
+:attr:`Engine.last_stats`.  The tick's host time is split into
+``engine.*`` spans (:func:`repro.obs.span`): admission, then inside
+``engine.tick`` the prepare, verify, upload, dispatch, sync and commit
+phases.  No span is open across a ``yield``: the time a consumer spends
+between events is its own.  :meth:`Engine.report` gives their delta over
+the last run under ``"host_spans"``.
 """
 from __future__ import annotations
 
@@ -76,7 +81,7 @@ from repro.core import verify
 from repro.core.scheduler import ServeStats
 from repro.distributed import collectives
 from repro.kernels.attention import ops as attn_ops
-from repro.kernels.fused_stack.ops import DispatchStats
+from repro.obs import SPANS, DispatchStats, span
 from repro.models import lm
 
 STATS = DispatchStats(keys=(
@@ -485,6 +490,8 @@ class Engine:
         self.last_prefix_cache: PrefixCache | None = None
         self.last_admission_order: list[int] = []
         self.last_attn_dispatch: dict[str, int] | None = None
+        #: ``engine.*`` span deltas of the last run (count, seconds)
+        self.last_spans: dict[str, dict] | None = None
         self._n_runs = 0
         self.mesh = mesh
         self.decode_plan: partition_mod.DecodeCachePlan | None = None
@@ -551,8 +558,9 @@ class Engine:
     def report(self) -> dict:
         """Serving placement + dispatch summary for the last run: which
         decode path compiled (pallas fast path vs jnp reference, with the
-        fallback reason), the mesh placement the plan committed, and the
-        engine/attention dispatch deltas.  Trace-time counters only move
+        fallback reason), the mesh placement the plan committed, the
+        engine/attention dispatch deltas, and the tick's host time by
+        ``engine.*`` span (``host_spans``).  Trace-time counters only move
         when a compilation happens, so a warm trace cache reports the
         mode's static dispatch with a note instead of zeros."""
         attn = dict(self.last_attn_dispatch or {})
@@ -597,6 +605,7 @@ class Engine:
                                 if plan is not None else {}),
             "dispatch": dict(self.last_dispatch or {}),
             "attn_dispatch": attn,
+            "host_spans": dict(self.last_spans or {}),
         }
 
     # -- admission ----------------------------------------------------------
@@ -693,6 +702,7 @@ class Engine:
         # slot-steps) — snapshot here, delta at the end.
         stats_before = STATS.snapshot()
         attn_before = attn_ops.STATS.snapshot()
+        spans_before = SPANS.snapshot()
 
         B, C, bs = self.slots, self.prefill_chunk, self.block_size
         paged = self.kv_layout == "paged"
@@ -931,40 +941,13 @@ class Engine:
                         dirty[b] = False
                     slot[b] = s
 
-        while True:
-            # one clock read per scheduler tick: every deadline check this
-            # tick and every latency stamped since the last tick sees the
-            # same timestamp (per-event reads made admission order change
-            # the deadline verdicts of unrelated requests)
-            now = time.perf_counter()
-            if n_latency_pending:
-                latencies.extend([(now - t0) * 1e3] * n_latency_pending)
-                n_latency_pending = 0
-            if n_ttft_pending:
-                ttfts.extend([(now - t0) * 1e3] * n_ttft_pending)
-                n_ttft_pending = 0
-            admit(now)
-            for ev in events:
-                yield ev
-            events.clear()
-            if any(pending_reset):
-                # jitted per-slot cache clear: freed slots restart at
-                # length 0 / zero SSM state before their new request's
-                # first prefill chunk
-                mask = jnp.asarray(np.asarray(pending_reset))
-                if paged:
-                    cache = self._reset(
-                        cache, mask,
-                        jnp.asarray(np.asarray(pending_len, np.int32)
-                                    .copy()))
-                else:
-                    cache = self._reset(cache, mask)
-                STATS.record("slot_reset")
-                pending_reset = [False] * B
-                pending_len = [0] * B
-            if all(s is None for s in slot):
-                break
-
+        def lanes():
+            """The tick's lane arrays, and (paged) its write barrier: every
+            block column this dispatch writes must be mapped, and mapped
+            privately — extension columns get fresh blocks, shared columns
+            are forked copy-on-write before the step runs.  Returns the
+            arrays, the prefill flags, the written blocks and the forks."""
+            nonlocal outstanding
             tokens = np.zeros((B, C), np.int32)
             counts = np.zeros((B,), np.int32)
             rids = np.zeros((B,), np.int32)
@@ -988,55 +971,49 @@ class Engine:
                     tokens[b, 0] = s.last
                     counts[b] = 1
                     n = 1
-                if paged:
-                    # write barrier: every block column this dispatch
-                    # writes must be mapped, and mapped privately —
-                    # extension columns get fresh blocks, shared columns
-                    # are forked copy-on-write before the step runs
-                    lo, hi = s.kv_len, s.kv_len + n
-                    for col in range(lo // bs, (hi - 1) // bs + 1):
-                        if col >= len(s.blocks):
-                            s.blocks.append(alloc.alloc())
-                            s.reserve -= 1
-                            outstanding -= 1
-                        elif alloc.refcount[s.blocks[col]] > 1:
-                            nb = alloc.alloc()
-                            s.reserve -= 1
-                            outstanding -= 1
-                            copies.append((s.blocks[col], nb))
-                            alloc.note_fork(s.blocks[col], nb)
-                            alloc.release(s.blocks[col])
-                            s.blocks[col] = nb
-                            stats.cow_forks += 1
-                            STATS.record("cow_fork")
-                        tables[b, col] = s.blocks[col]
-                        writers.add(s.blocks[col])
-            for src, dst in copies:
-                cache = self._copy(cache, jnp.asarray(src, jnp.int32),
-                                   jnp.asarray(dst, jnp.int32))
-            if paged and self.verify_mode != "off":
-                rows = [(tuple(s.blocks), s.kv_len + int(counts[b]))
-                        for b, s in enumerate(slot) if s is not None]
-                state = verify.BlockTableState(
-                    num_blocks=self.kv_num_blocks, block_size=bs,
-                    refcounts=tuple(alloc.refcount),
-                    free=alloc.free_blocks(),
-                    tables=tuple(r[0] for r in rows),
-                    lengths=tuple(r[1] for r in rows),
-                    cached=(prefix.cached_blocks() if prefix is not None
-                            else ()),
-                    writers=tuple(sorted(writers)))
-                verify.enforce(verify.check_block_tables(state),
-                               self.verify_mode, subject="engine tick")
+                if not paged:
+                    continue
+                lo, hi = s.kv_len, s.kv_len + n
+                for col in range(lo // bs, (hi - 1) // bs + 1):
+                    if col >= len(s.blocks):
+                        s.blocks.append(alloc.alloc())
+                        s.reserve -= 1
+                        outstanding -= 1
+                    elif alloc.refcount[s.blocks[col]] > 1:
+                        nb = alloc.alloc()
+                        s.reserve -= 1
+                        outstanding -= 1
+                        copies.append((s.blocks[col], nb))
+                        alloc.note_fork(s.blocks[col], nb)
+                        alloc.release(s.blocks[col])
+                        s.blocks[col] = nb
+                        stats.cow_forks += 1
+                        STATS.record("cow_fork")
+                    tables[b, col] = s.blocks[col]
+                    writers.add(s.blocks[col])
+            return ((tokens, counts, rids, tidx, temps), was_prefill,
+                    writers, copies)
 
-            step_in = (self.params, cache)
-            if paged:
-                step_in += (jnp.asarray(tables),)
-            nxt, cache = self._step(
-                *step_in, jnp.asarray(tokens), jnp.asarray(counts),
-                jnp.asarray(rids), jnp.asarray(tidx), jnp.asarray(temps),
-                key)
-            nxt = np.asarray(nxt)
+        def check_tables(counts, writers) -> None:
+            rows = [(tuple(s.blocks), s.kv_len + int(counts[b]))
+                    for b, s in enumerate(slot) if s is not None]
+            state = verify.BlockTableState(
+                num_blocks=self.kv_num_blocks, block_size=bs,
+                refcounts=tuple(alloc.refcount),
+                free=alloc.free_blocks(),
+                tables=tuple(r[0] for r in rows),
+                lengths=tuple(r[1] for r in rows),
+                cached=(prefix.cached_blocks() if prefix is not None
+                        else ()),
+                writers=tuple(sorted(writers)))
+            verify.enforce(verify.check_block_tables(state),
+                           self.verify_mode, subject="engine tick")
+
+        def commit(nxt: np.ndarray, counts: np.ndarray,
+                   was_prefill: list[bool]) -> None:
+            """Account the dispatched tick, advance every live slot, and
+            emit its tokens and completions."""
+            nonlocal n_ttft_pending, util_acc, util_n
             stats.step_dispatches += 1
             STATS.record("mixed_step")
 
@@ -1105,6 +1082,61 @@ class Engine:
                 util_acc += live / (B * self.max_len)
                 util_n += 1
 
+        while True:
+            # one clock read per scheduler tick: every deadline check this
+            # tick and every latency stamped since the last tick sees the
+            # same timestamp (per-event reads made admission order change
+            # the deadline verdicts of unrelated requests)
+            now = time.perf_counter()
+            if n_latency_pending:
+                latencies.extend([(now - t0) * 1e3] * n_latency_pending)
+                n_latency_pending = 0
+            if n_ttft_pending:
+                ttfts.extend([(now - t0) * 1e3] * n_ttft_pending)
+                n_ttft_pending = 0
+            with span("engine.admit"):
+                admit(now)
+            for ev in events:
+                yield ev
+            events.clear()
+            # admission marks a slot for reset only as it fills it
+            if all(s is None for s in slot):
+                break
+            with span("engine.tick"):
+                with span("engine.prepare"):
+                    if any(pending_reset):
+                        # jitted per-slot cache clear: freed slots restart
+                        # at length 0 / zero SSM state before their new
+                        # request's first prefill chunk
+                        mask = jnp.asarray(np.asarray(pending_reset))
+                        if paged:
+                            cache = self._reset(cache, mask, jnp.asarray(
+                                np.asarray(pending_len, np.int32).copy()))
+                        else:
+                            cache = self._reset(cache, mask)
+                        STATS.record("slot_reset")
+                        pending_reset = [False] * B
+                        pending_len = [0] * B
+                    arrays, was_prefill, writers, copies = lanes()
+                    for src, dst in copies:
+                        cache = self._copy(cache, jnp.asarray(src, jnp.int32),
+                                           jnp.asarray(dst, jnp.int32))
+                counts = arrays[1]
+                if paged and self.verify_mode != "off":
+                    with span("engine.verify"):
+                        check_tables(counts, writers)
+                with span("engine.upload"):
+                    step_in = (self.params, cache)
+                    if paged:
+                        step_in += (jnp.asarray(tables),)
+                    step_in += tuple(jnp.asarray(a) for a in arrays)
+                with span("engine.dispatch"):
+                    nxt, cache = self._step(*step_in, key)
+                with span("engine.sync"):
+                    nxt = np.asarray(nxt)
+                with span("engine.commit"):
+                    commit(nxt, counts, was_prefill)
+
             # the tick's commits are final: stream them before the next
             # dispatch so a consumer never waits on future batch-mates
             for ev in events:
@@ -1136,4 +1168,6 @@ class Engine:
         self.last_stats = stats
         self.last_dispatch = STATS.delta(stats_before)
         self.last_attn_dispatch = attn_ops.STATS.delta(attn_before)
+        self.last_spans = {k: v for k, v in SPANS.delta(spans_before).items()
+                           if k.startswith("engine.")}
         return completions  # type: ignore[return-value]

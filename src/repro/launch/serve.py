@@ -39,7 +39,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import RuntimeConfig
 from repro.core.scheduler import ServeStats
-from repro.kernels.fused_stack.ops import DispatchStats
+from repro.obs import DispatchStats
 from repro.launch import compile_cache, engine as engine_mod
 from repro.models import lm
 

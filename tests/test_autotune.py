@@ -88,6 +88,13 @@ class TestDecisions:
         text = net.explain()
         assert "autotune" in text
 
+    def test_floor_is_timed_as_a_setup_span(self, rng, tmp_path):
+        fn = _norm_chain_fn()
+        x, w = _args(rng)
+        net = api.optimize(fn, x, w, config=_cfg(tmp_path))
+        assert net.setup_spans["optimize.floor"]["count"] == 1
+        assert "optimize.floor" in net.explain()
+
     def test_variant_never_slower_than_baseline(self, rng, tmp_path):
         """The hard floor: whatever was committed measured no slower than
         the baseline in every phase (modulo the declared slack)."""

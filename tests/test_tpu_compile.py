@@ -136,3 +136,47 @@ def test_nhwc_backward_compiles(compile_for):
     compile_for(lambda x, s, o, g: nhwc_bwd.fused_nhwc_bwd_call(
                     prog, x, {}, {"s": s, "o": o}, g),
                 IMAGE, CHANNEL, CHANNEL, ((1, 112, 112, 64), jnp.float32))
+
+
+def _kernel_bodies(text: str) -> list[bytes]:
+    """The Mosaic body of every Pallas kernel call in a compiled program."""
+    import base64
+    import re
+
+    return [base64.b64decode(m) for m in re.findall(
+        r'custom_call_target="tpu_custom_call".*?"body":"([A-Za-z0-9+/=]+)"',
+        text)]
+
+
+def _rows_glu_grad(gate, up):
+    prog = stacks.glu_program("silu")
+    return jax.value_and_grad(lambda g, u: jnp.sum(
+        fused_ops.fused_stack_apply(prog, {"gate": g, "up": u}, {},
+                                    mode="brainslug")["y"].astype(
+            jnp.float32)), argnums=(0, 1))(gate, up)
+
+
+@pytest.mark.parametrize("fn, shapes, names", [
+    (decode.paged_flash_decode,
+     [((SLOTS, HEADS, 1, HEAD_DIM), jnp.bfloat16),
+      ((256, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16),
+      ((256, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16),
+      ((SLOTS, 32), jnp.int32), ((SLOTS,), jnp.int32)],
+     {b"paged_decode_kernel"}),
+    (lambda x, s, o: nhwc.fused_nhwc_call(_vgg_stage(), x, {"s": s, "o": o}),
+     [IMAGE, CHANNEL, CHANNEL], {b"nhwc_fwd_kernel"}),
+    (lambda x, s, o, g: nhwc_bwd.fused_nhwc_bwd_call(
+        _vgg_stage(), x, {}, {"s": s, "o": o}, g),
+     [IMAGE, CHANNEL, CHANNEL, ((1, 112, 112, 64), jnp.float32)],
+     {b"nhwc_bwd_kernel"}),
+    (_rows_glu_grad, [((2048, D_FF), jnp.bfloat16)] * 2,
+     {b"rows_fwd_kernel", b"rows_bwd_kernel"}),
+], ids=["paged_decode", "nhwc_fwd", "nhwc_bwd", "rows_fwd_bwd"])
+def test_compiled_kernels_carry_their_names(compile_for, fn, shapes, names):
+    """Each ``pallas_call`` names its kernel; the name reaches the Mosaic
+    body, where a trace reader can find it.  Generated backwards keep the
+    ``_bwd_kernel`` ending that tells them apart from forwards."""
+    bodies = _kernel_bodies(compile_for(fn, *shapes))
+    assert bodies
+    found = {n for n in names if any(n in b for b in bodies)}
+    assert found == names
