@@ -91,6 +91,10 @@ STATS = DispatchStats(keys=(
     "decode_slot_steps",   # slot-units of decode dispatch work
     "idle_slot_steps",     # lane-evaluation units that consumed no token
     "cow_fork",            # copy-on-write block forks (paged layout)
+    "paged_blocks_live",   # paged: ceil(length / bs) per token-consuming
+                           # lane and model evaluation (blocks attended)
+    "paged_blocks_grid",   # paged: table width MB per such lane and
+                           # evaluation (blocks the kernel's grid spans)
 ))
 
 
@@ -559,7 +563,9 @@ class Engine:
         """Serving placement + dispatch summary for the last run: which
         decode path compiled (pallas fast path vs jnp reference, with the
         fallback reason), the mesh placement the plan committed, the
-        engine/attention dispatch deltas, and the tick's host time by
+        engine/attention dispatch deltas (paged: ``paged_blocks_live`` over
+        ``paged_blocks_grid`` is the share of the paged-decode kernel's
+        grid that holds live blocks), and the tick's host time by
         ``engine.*`` span (``host_spans``).  Trace-time counters only move
         when a compilation happens, so a warm trace cache reports the
         mode's static dispatch with a note instead of zeros."""
@@ -1043,6 +1049,11 @@ class Engine:
                 lo = s.kv_len
                 s.kv_len = lo + n
                 if paged:
+                    # the evaluation that consumes token t attends to
+                    # lo + t + 1 positions
+                    STATS.record("paged_blocks_live", sum(
+                        -(-(lo + t + 1) // bs) for t in range(n)))
+                    STATS.record("paged_blocks_grid", n * self.max_blocks)
                     for col in range(lo // bs, (s.kv_len - 1) // bs + 1):
                         alloc.note_fill(s.blocks[col],
                                         min(s.kv_len - col * bs, bs))

@@ -153,12 +153,38 @@ class TestPagedDecode:
         lens = jnp.asarray(lengths, jnp.int32)
         return q, k_pool, v_pool, table, lens
 
-    def test_kernel_matches_ref_on_ragged_lengths(self):
-        q, kp, vp, tbl, lens = self._case([9, 4, 1, 12])
+    @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
+                                            (jnp.bfloat16, 1e-2)],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("h, g", [(4, 4), (4, 2), (8, 2)],
+                             ids=["mha", "gqa2", "gqa4"])
+    def test_kernel_matches_ref_on_ragged_lengths(self, h, g, dtype, tol):
+        """Lengths at every block edge (empty, one position, one short of
+        a block, a block, one past it, the whole table), with each slot's
+        table tail past its live range holding the allocator's sentinel
+        ``n``.  The pools come in the query's dtype, as the serving path
+        casts them; the reference runs in float32 on the same values."""
+        rng = np.random.default_rng(h * 10 + g)
+        bs, mb, n, d = 4, 3, 16, 8
+        lengths = [0, 1, bs - 1, bs, bs + 1, mb * bs]
+        b = len(lengths)
+        q = jnp.asarray(rng.standard_normal((b, h, 1, d)), dtype)
+        kp = jnp.asarray(rng.standard_normal((n, g, bs, d)), dtype)
+        vp = jnp.asarray(rng.standard_normal((n, g, bs, d)), dtype)
+        free = iter(rng.permutation(n).tolist())
+        table = np.full((b, mb), n, np.int32)
+        for i, length in enumerate(lengths):
+            for j in range(-(-length // bs)):
+                table[i, j] = next(free)
+        tbl, lens = jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
         out_k = attn_ops.paged_flash_decode(q, kp, vp, tbl, lens)
-        out_r = attn_ref.paged_decode_ref(q, kp, vp, tbl, lens)
-        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                                   atol=1e-5, rtol=1e-5)
+        assert out_k.shape == q.shape and out_k.dtype == dtype
+        f32 = [x.astype(jnp.float32) for x in (q, kp, vp)]
+        out_r = attn_ref.paged_decode_ref(*f32, tbl, lens)
+        out_k = np.asarray(out_k.astype(jnp.float32))
+        assert (out_k[0] == 0.0).all()
+        np.testing.assert_allclose(out_k, np.asarray(out_r),
+                                   atol=tol, rtol=tol)
 
     def test_gather_matches_dense_reference_bitwise(self):
         """The xla-mode paged path is a gather + the dense reference — on
@@ -266,6 +292,33 @@ class TestPagedEngine:
         assert np.array_equal(out[0].tokens, out[1].tokens)
         assert e.last_stats.cow_forks >= 1
         assert e.last_stats.prefix_hit_tokens == 7   # plen-1 cap
+
+    def test_block_counters_match_served_lengths(self, paged_server):
+        """``paged_blocks_live`` counts the blocks each token-consuming
+        lane attends to, per model evaluation: a request of prompt ``p``
+        and ``m`` new tokens consumes ``p + m - 1`` tokens, at lengths
+        ``1 .. p + m - 1``.  ``paged_blocks_grid`` counts the table width
+        for each; both reach ``report()``."""
+        rng = np.random.default_rng(11)
+        plens, news = [5, 3, 7, 1, 9], [4, 6, 2, 5, 3]
+        reqs = [Request(request_id=i,
+                        prompt=rng.integers(1, paged_server.cfg.vocab_size,
+                                            p).tolist(),
+                        max_new_tokens=m)
+                for i, (p, m) in enumerate(zip(plens, news))]
+        e = paged_server.engine(slots=2, prefill_chunk=4, kv_layout="paged",
+                                kv_block_size=4, prefix_sharing=False,
+                                verify_mode="strict")
+        out = e.run(reqs)
+        assert all(c.status == "ok" for c in out)
+        bs, mb = 4, 24 // 4
+        live = sum(-(-x // bs) for p, m in zip(plens, news)
+                   for x in range(1, p + m))
+        grid = sum(p + m - 1 for p, m in zip(plens, news)) * mb
+        got = e.report()["dispatch"]
+        assert got["paged_blocks_live"] == live
+        assert got["paged_blocks_grid"] == grid
+        assert live <= grid
 
     def test_no_block_leak_after_run(self, paged_server):
         reqs = _shared_prefix_queue(paged_server.cfg.vocab_size, n=9,

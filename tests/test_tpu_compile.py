@@ -72,14 +72,19 @@ def compile_for(one_chip, no_compile_cache, monkeypatch):
     return compile_
 
 
-def test_paged_decode_compiles(compile_for):
+@pytest.mark.parametrize("kv_heads", [HEADS, 8], ids=["mha", "gqa"])
+def test_paged_decode_compiles(compile_for, kv_heads):
+    """deepseek-7b widths (32 query heads, 32 KV heads) and a GQA width
+    (32 / 8): each compiles to exactly one paged-decode kernel call."""
     n_blocks = SLOTS * MAX_LEN // BLOCK
-    compile_for(decode.paged_flash_decode,
-                ((SLOTS, HEADS, 1, HEAD_DIM), jnp.bfloat16),
-                ((n_blocks, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16),
-                ((n_blocks, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16),
-                ((SLOTS, MAX_LEN // BLOCK), jnp.int32),
-                ((SLOTS,), jnp.int32))
+    text = compile_for(decode.paged_flash_decode,
+                       ((SLOTS, HEADS, 1, HEAD_DIM), jnp.bfloat16),
+                       ((n_blocks, kv_heads, BLOCK, HEAD_DIM), jnp.bfloat16),
+                       ((n_blocks, kv_heads, BLOCK, HEAD_DIM), jnp.bfloat16),
+                       ((SLOTS, MAX_LEN // BLOCK), jnp.int32),
+                       ((SLOTS,), jnp.int32))
+    bodies = _kernel_bodies(text)
+    assert len(bodies) == 1 and b"paged_decode_kernel" in bodies[0]
 
 
 def test_dense_decode_compiles(compile_for):
