@@ -17,6 +17,7 @@ _MODULES = {
     "granite-moe-3b-a800m": "granite_moe_3b",
     "llama4-maverick-400b-a17b": "llama4_maverick",
     "zamba2-7b": "zamba2_7b",
+    "granite-4.0-h-small": "granite_4_h_small",
     "brainslug-cnn": "brainslug_cnn",
 }
 

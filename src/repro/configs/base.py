@@ -41,14 +41,30 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_conv_width: int = 4
+    ssm_conv_bias: bool = False        # bias on the depthwise conv
     # --- hybrid --------------------------------------------------------------
     attn_layer_period: int = 0         # zamba2: shared attn every k layers
+    # kinds of one scanned period of distinct layers ("mamba_moe",
+    # "attn_moe"); () = the family's own plan (see models.lm.layer_plan)
+    layer_pattern: tuple[str, ...] = ()
     # --- modality ------------------------------------------------------------
     is_encoder: bool = False
     frontend: str | None = None        # 'audio_frames' | 'vision_patches'
     n_prefix_tokens: int = 0           # vlm: image patches prepended
     frontend_dim: int = 0              # stub embedding dim fed by input_specs
+    # --- scalings (muP) ----------------------------------------------------
+    embedding_multiplier: float | None = None   # None: sqrt(d) if tied
+    residual_multiplier: float = 1.0   # scales each sub-layer's output
+    attention_multiplier: float | None = None   # None: 1/sqrt(head_dim)
+    logits_scaling: float = 1.0        # logits are divided by it
+    use_rope: bool = True              # False: no positional encoding
+    # --- expert parallelism --------------------------------------------------
+    # (first, count): the experts of ``n_experts`` this chip holds; the
+    # router keeps all ``n_experts`` outputs and ``top_k``, and only the
+    # held experts' terms are computed.  None = all of them.
+    expert_share: tuple[int, int] | None = None
     # --- numerics ------------------------------------------------------------
+    norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     source: str = ""                   # provenance note ([arXiv/hf; tier])
 
@@ -65,6 +81,11 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the experts computed here."""
+        return self.expert_share or (0, self.n_experts)
 
     @property
     def is_attention_free(self) -> bool:
@@ -87,20 +108,29 @@ class ModelConfig:
         return _count_params(self, active_only=True)
 
     def reduced(self) -> "ModelConfig":
-        """CPU-smoke variant: same family/wiring, tiny dims."""
+        """CPU-smoke variant: same family/wiring, tiny dims (one period of
+        a layer pattern, a quarter of the reduced experts held)."""
+        if self.layer_pattern:
+            n_layers = len(self.layer_pattern)
+        elif self.attn_layer_period:
+            n_layers = min(self.n_layers, 2 * max(self.attn_layer_period, 2))
+        else:
+            n_layers = min(self.n_layers, 4)
+        n_experts = min(self.n_experts, 8) if self.n_experts else 0
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
-            n_layers=min(self.n_layers, 4 if self.attn_layer_period == 0
-                         else 2 * max(self.attn_layer_period, 2)),
+            n_layers=n_layers,
             d_model=128,
             n_heads=4,
             n_kv_heads=max(1, min(self.n_kv_heads, 2)),
             d_head=32,
             d_ff=max(64, min(self.d_ff, 256)),
             vocab_size=min(self.vocab_size, 512),
-            n_experts=min(self.n_experts, 8) if self.n_experts else 0,
+            n_experts=n_experts,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            expert_share=((0, max(2, n_experts // 4)) if self.expert_share
+                          else None),
             shared_expert_ff=128 if self.shared_expert_ff else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=32 if self.ssm_state else 64,
@@ -205,7 +235,7 @@ def _count_params(cfg: ModelConfig, active_only: bool) -> int:
     moe_mlp = 0
     if cfg.n_experts:
         per_expert = 3 * d * cfg.d_ff
-        n_used = cfg.top_k if active_only else cfg.n_experts
+        n_used = cfg.top_k if active_only else cfg.held_experts[1]
         moe_mlp = n_used * per_expert + d * cfg.n_experts   # + router
         if cfg.shared_expert_ff:
             moe_mlp += 3 * d * cfg.shared_expert_ff
@@ -214,9 +244,15 @@ def _count_params(cfg: ModelConfig, active_only: bool) -> int:
         di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
         ssm = d * (2 * di + 2 * n + h) + di * d \
             + cfg.ssm_conv_width * (di + 2 * n) + 3 * h
+        if cfg.ssm_conv_bias:
+            ssm += di + 2 * n
     hybrid_shared_counted = False
     for i in range(cfg.n_layers):
-        if cfg.family == "ssm":
+        if cfg.layer_pattern:
+            kind = cfg.layer_pattern[i % len(cfg.layer_pattern)]
+            total += (ssm if kind.startswith("mamba") else attn) \
+                + moe_mlp + 2 * d
+        elif cfg.family == "ssm":
             total += ssm + d                                # + norm
         elif cfg.family == "hybrid":
             is_attn = (cfg.attn_layer_period

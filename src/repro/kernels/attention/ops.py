@@ -49,13 +49,17 @@ def _bwd(causal, block_q, block_k, scale, res, g):
 flash_attention.defvjp(_fwd, _bwd)
 
 
-def flash_decode(q, k, v, lengths, *, block_k: int = 512):
+def flash_decode(q, k, v, lengths, *, block_k: int = 512,
+                 scale: float | None = None):
     """Inference-only (no vjp needed on the decode path)."""
-    return decode_mod.flash_decode(q, k, v, lengths, block_k=block_k)
+    return decode_mod.flash_decode(q, k, v, lengths, block_k=block_k,
+                                   scale=scale)
 
 
-def paged_flash_decode(q, k_pool, v_pool, table, lengths):
+def paged_flash_decode(q, k_pool, v_pool, table, lengths, *,
+                       scale: float | None = None):
     """Block-mapped flash decode (inference-only, like ``flash_decode``):
     the (B, MB) block table routes each grid step to its physical pool
     block via scalar prefetch."""
-    return decode_mod.paged_flash_decode(q, k_pool, v_pool, table, lengths)
+    return decode_mod.paged_flash_decode(q, k_pool, v_pool, table, lengths,
+                                         scale=scale)

@@ -95,6 +95,11 @@ STATS = DispatchStats(keys=(
                            # lane and model evaluation (blocks attended)
     "paged_blocks_grid",   # paged: table width MB per such lane and
                            # evaluation (blocks the kernel's grid spans)
+    "moe_routed_assignments",  # token x top_k expert assignments, over
+                               # every MoE layer (host count)
+    "moe_held_assignments",    # those that landed on the experts held
+                               # here (cfg.expert_share; device count)
+    "ssm_state_updates",   # Mamba layer x token-consuming lane evaluation
 ))
 
 
@@ -342,13 +347,17 @@ class PrefixCache:
         self._order.clear()
 
 
-def _mixed_step_fn(cfg: ModelConfig, rt: RuntimeConfig):
+def _mixed_step_fn(cfg: ModelConfig, rt: RuntimeConfig,
+                   count_experts: bool = False):
     """The raw mixed prefill/decode step for (cfg, rt) — what
     :func:`_jitted_mixed_step` jits directly and what a mesh-backed Engine
     wraps in its shard_map region first (with the head-localized config;
     see ``Engine._build_sharded_step``).  The paged variant takes the
     block tables as an extra operand — host-side mapping state, not cache
-    state, so it is never donated."""
+    state, so it is never donated.  ``count_experts`` adds a third
+    output: the step's expert assignments on the held experts
+    (``lm.decode_step(count_experts=True)``), summed over its
+    evaluations."""
     vocab = cfg.vocab_size
     paged = rt.kv_layout == "paged"
 
@@ -359,23 +368,30 @@ def _mixed_step_fn(cfg: ModelConfig, rt: RuntimeConfig):
         Slot b consumes tokens[b, :counts[b]] (0 = idle lane); returns
         the token each slot samples from its last consumed position."""
         def body(t, carry):
-            logits_last, cache = carry
+            logits_last, cache = carry[:2]
             active = t < counts
             tok = jax.lax.dynamic_slice_in_dim(tokens, t, 1, axis=1)
-            logits, cache = lm.decode_step(params, cache, tok, cfg, rt,
-                                           active, block_tables=tables)
+            out = lm.decode_step(params, cache, tok, cfg, rt, active,
+                                 block_tables=tables,
+                                 count_experts=count_experts)
+            logits, cache = out[:2]
             logits_last = jnp.where(active[:, None],
                                     logits[:, 0].astype(jnp.float32),
                                     logits_last)
+            if count_experts:
+                return logits_last, cache, carry[2] + out[2]
             return logits_last, cache
 
         logits0 = jnp.zeros((tokens.shape[0], vocab), jnp.float32)
+        init = (logits0, cache)
+        if count_experts:
+            init += (jnp.zeros((), jnp.int32),)
         # traced trip count (lowers to a while_loop): in decode-only
         # steady state max(counts) == 1, so the step does one model
         # evaluation, not C — dead all-inactive iterations would multiply
         # every generated token's cost by the window width
-        logits_last, cache = jax.lax.fori_loop(
-            0, jnp.max(counts), body, (logits0, cache))
+        final = jax.lax.fori_loop(0, jnp.max(counts), body, init)
+        logits_last, cache = final[:2]
 
         def sample_row(logits, rid, ti, temp):
             key = jax.random.fold_in(jax.random.fold_in(base_key, rid),
@@ -386,7 +402,7 @@ def _mixed_step_fn(cfg: ModelConfig, rt: RuntimeConfig):
             return jnp.where(temp > 0.0, samp, greedy)
 
         nxt = jax.vmap(sample_row)(logits_last, rids, tidx, temps)
-        return nxt, cache
+        return (nxt, cache) + final[2:]
 
     if not paged:
         def dense_step(params, cache, tokens, counts, rids, tidx, temps,
@@ -404,8 +420,10 @@ def _jitted_mixed_step(cfg: ModelConfig, rt: RuntimeConfig):
     the token-window *shape*, not on any per-engine state).  The cache is
     donated: run() rebinds it from the step's return, and in place the
     per-slot where-select KV write stays a masked update instead of a full
-    cache copy per token (no-op warning on CPU)."""
-    return jax.jit(_mixed_step_fn(cfg, rt), donate_argnums=(1,))
+    cache copy per token (no-op warning on CPU).  A model with experts
+    also returns its held-expert assignment count."""
+    return jax.jit(_mixed_step_fn(cfg, rt, count_experts=bool(cfg.n_experts)),
+                   donate_argnums=(1,))
 
 
 # Slot recycling rewrites one batch column of every cache leaf; donating
@@ -488,6 +506,11 @@ class Engine:
                                and self.kv_layout == "paged"
                                and cfg.family not in ("ssm", "hybrid"))
         self.verify_mode = verify_mode
+        plan = lm.layer_plan(cfg)
+        kinds = plan.superblock * plan.n_super + plan.tail
+        #: Mamba layers and MoE layers per model evaluation
+        self.n_mamba_layers = sum(k in lm.MAMBA_KINDS for k in kinds)
+        self.n_moe_layers = sum(k.endswith("_moe") for k in kinds)
         self.last_stats: ServeStats | None = None
         self.last_dispatch: dict[str, int] | None = None
         self.last_allocator: BlockAllocator | None = None
@@ -1046,6 +1069,12 @@ class Engine:
                     STATS.record("decode_slot_steps")
                     stats.idle_slot_steps += window - 1
                     STATS.record("idle_slot_steps", window - 1)
+                if self.n_mamba_layers:
+                    STATS.record("ssm_state_updates",
+                                 n * self.n_mamba_layers)
+                if self.n_moe_layers:
+                    STATS.record("moe_routed_assignments",
+                                 n * self.cfg.top_k * self.n_moe_layers)
                 lo = s.kv_len
                 s.kv_len = lo + n
                 if paged:
@@ -1142,8 +1171,11 @@ class Engine:
                         step_in += (jnp.asarray(tables),)
                     step_in += tuple(jnp.asarray(a) for a in arrays)
                 with span("engine.dispatch"):
-                    nxt, cache = self._step(*step_in, key)
+                    nxt, cache, *held = self._step(*step_in, key)
                 with span("engine.sync"):
+                    if held:
+                        nxt, held = jax.device_get((nxt, held[0]))
+                        STATS.record("moe_held_assignments", int(held))
                     nxt = np.asarray(nxt)
                 with span("engine.commit"):
                     commit(nxt, counts, was_prefill)

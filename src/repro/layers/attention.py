@@ -1,4 +1,6 @@
-"""GQA attention with RoPE: train (flash / chunked / full) + decode paths.
+"""GQA attention with RoPE (or none: ``cfg.use_rope``): train (flash /
+chunked / full) + decode paths.  Scores are scaled by
+``cfg.attention_multiplier``, or ``1/sqrt(head_dim)`` when it is unset.
 
 Schedules:
 * ``brainslug``  — the depth-first Pallas flash kernel (scores never hit HBM)
@@ -71,13 +73,15 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
 # Core attention math (xla / barrier paths)
 # ---------------------------------------------------------------------------
 
-def _full_attention(q, k, v, causal: bool, barrier: bool) -> jnp.ndarray:
+def _full_attention(q, k, v, causal: bool, barrier: bool,
+                    scale: float | None = None) -> jnp.ndarray:
     """GQA without kv expansion: q heads grouped against their kv head in
     the einsum — no (H/G)x repeated copy of K/V is materialized."""
     b, h, sq, hd = q.shape
     g, sk = k.shape[1], k.shape[2]
     rep = h // g
-    scale = 1.0 / hd ** 0.5
+    if scale is None:
+        scale = 1.0 / hd ** 0.5
     qg = q.reshape(b, g, rep, sq, hd)
     s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k,
                    preferred_element_type=jnp.float32) * scale
@@ -96,7 +100,8 @@ def _full_attention(q, k, v, causal: bool, barrier: bool) -> jnp.ndarray:
 
 
 def _chunked_attention(q, k, v, causal: bool, block_k: int = 512,
-                       unroll: bool = False) -> jnp.ndarray:
+                       unroll: bool = False,
+                       scale: float | None = None) -> jnp.ndarray:
     """Online-softmax over KV chunks at the JAX level (lax.scan).  Bounded
     memory for long sequences without a custom kernel — the xla-mode path.
 
@@ -107,7 +112,8 @@ def _chunked_attention(q, k, v, causal: bool, block_k: int = 512,
     b, h, sq, hd = q.shape
     g, sk = k.shape[1], k.shape[2]
     rep = h // g
-    scale = 1.0 / hd ** 0.5
+    if scale is None:
+        scale = 1.0 / hd ** 0.5
     pad = (-sk) % block_k
     if pad:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -173,11 +179,13 @@ def apply(params, x: jnp.ndarray, cfg: ModelConfig, rt: RuntimeConfig,
     """Full-sequence attention (train / prefill)."""
     b, s, _ = x.shape
     causal = not cfg.is_encoder
+    scale = cfg.attention_multiplier
     q, k, v = _project(params, x, cfg)
-    if positions is None:
-        positions = jnp.arange(s)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        if positions is None:
+            positions = jnp.arange(s)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
 
     if rt.attn_impl == "skip_core":
         # cost-probe mode: the quadratic core is bypassed (o = q + 0*v so
@@ -186,13 +194,14 @@ def apply(params, x: jnp.ndarray, cfg: ModelConfig, rt: RuntimeConfig,
         o = q + 0.0 * jnp.mean(v) + 0.0 * jnp.mean(k)
     elif rt.mode == "brainslug":
         o = attn_ops.flash_attention(q, k, v, causal, rt.attn_block_q,
-                                     rt.attn_block_k)
+                                     rt.attn_block_k, scale)
     elif rt.mode == "barrier":
-        o = _full_attention(q, k, v, causal, barrier=True)
+        o = _full_attention(q, k, v, causal, barrier=True, scale=scale)
     elif s > FULL_SCORE_MAX_SEQ:
-        o = _chunked_attention(q, k, v, causal, unroll=rt.scan_unroll)
+        o = _chunked_attention(q, k, v, causal, unroll=rt.scan_unroll,
+                               scale=scale)
     else:
-        o = _full_attention(q, k, v, causal, barrier=False)
+        o = _full_attention(q, k, v, causal, barrier=False, scale=scale)
 
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
     return jnp.einsum("bsk,kd->bsd", o, params["wo"])
@@ -265,8 +274,9 @@ def _decode_paged(params, x_t: jnp.ndarray, cache: PagedKVCache,
     n, _, bs, _ = cache.k_pool.shape
     q, k_new, v_new = _project(params, x_t, cfg)          # (B,*,1,hd)
     pos = cache.length                                     # (B,)
-    q = rope(q, pos[:, None], cfg.rope_theta)
-    k_new = rope(k_new, pos[:, None], cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, pos[:, None], cfg.rope_theta)
+        k_new = rope(k_new, pos[:, None], cfg.rope_theta)
 
     # Scatter write through the table: position `pos` lands in physical
     # block table[b, pos // bs] at offset pos % bs.  The host pre-maps
@@ -291,12 +301,12 @@ def _decode_paged(params, x_t: jnp.ndarray, cache: PagedKVCache,
         attn_ops.STATS.record("paged_decode_pallas")
         o = attn_ops.paged_flash_decode(
             q, k_pool.astype(q.dtype), v_pool.astype(q.dtype), table,
-            lengths)
+            lengths, scale=cfg.attention_multiplier)
     else:
         attn_ops.STATS.record("paged_decode_ref")
         o = attn_ref.paged_decode_ref(
             q, k_pool.astype(q.dtype), v_pool.astype(q.dtype), table,
-            lengths)
+            lengths, scale=cfg.attention_multiplier)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * hd)
     out = jnp.einsum("bsk,kd->bsd", o, params["wo"])
     if rt.tp_axis:
@@ -331,8 +341,9 @@ def decode(params, x_t: jnp.ndarray, cache, cfg: ModelConfig,
     h, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q, k_new, v_new = _project(params, x_t, cfg)          # (B,*,1,hd)
     pos = cache.length                                     # (B,)
-    q = rope(q, pos[:, None], cfg.rope_theta)
-    k_new = rope(k_new, pos[:, None], cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, pos[:, None], cfg.rope_theta)
+        k_new = rope(k_new, pos[:, None], cfg.rope_theta)
 
     # where-select write at position `length`.  A per-batch scatter was
     # measured 5x worse in XLA's byte accounting (scatter is charged ~10x
@@ -351,11 +362,12 @@ def decode(params, x_t: jnp.ndarray, cache, cfg: ModelConfig,
     if rt.mode == "brainslug":
         attn_ops.STATS.record("decode_pallas")
         o = attn_ops.flash_decode(q, k.astype(q.dtype), v.astype(q.dtype),
-                                  lengths, block_k=rt.decode_block_k)
+                                  lengths, block_k=rt.decode_block_k,
+                                  scale=cfg.attention_multiplier)
     else:
         attn_ops.STATS.record("decode_ref")
         o = attn_ref.decode_ref(q, k.astype(q.dtype), v.astype(q.dtype),
-                                lengths)
+                                lengths, scale=cfg.attention_multiplier)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * hd)
     out = jnp.einsum("bsk,kd->bsd", o, params["wo"])
     if rt.tp_axis:

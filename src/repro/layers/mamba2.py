@@ -1,5 +1,6 @@
-"""Mamba2 (SSD) mixer layer: projections, causal depthwise conv, SSD scan,
-gated RMSNorm, out-projection.
+"""Mamba2 (SSD) mixer layer: projections, causal depthwise conv (with a
+bias where ``cfg.ssm_conv_bias``), SSD scan, gated RMSNorm (eps
+``cfg.norm_eps``), out-projection.
 
 The gated-norm epilogue ``y = rmsnorm(y * silu(z)) * scale`` is a BrainSlug
 stack (silu → mul → row-norm) and runs through the fused dispatcher; the SSD
@@ -25,8 +26,8 @@ from repro.layers import base
 def init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     d, di, n, h, cw = (cfg.d_model, cfg.d_inner, cfg.ssm_state,
                        cfg.ssm_heads, cfg.ssm_conv_width)
-    ks = jax.random.split(key, 8)
-    return {
+    ks = jax.random.split(key, 9)
+    p = {
         "wz": base.boxed(ks[0], (d, di), ("fsdp", "ffn"), dtype=dtype),
         "wx": base.boxed(ks[1], (d, di), ("fsdp", "ffn"), dtype=dtype),
         "wB": base.boxed(ks[2], (d, n), ("fsdp", None), dtype=dtype),
@@ -49,6 +50,11 @@ def init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
         "wo": base.boxed(ks[0], (di, d), ("ffn", "fsdp"),
                          scale=1.0 / di ** 0.5, dtype=dtype),
     }
+    if cfg.ssm_conv_bias:
+        # one bias over the conv's channels, in [x, B, C] order
+        p["conv_bias"] = base.boxed(ks[8], (di + 2 * n,), (None,),
+                                    scale=0.1, dtype=dtype)
+    return p
 
 
 def _causal_conv(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -94,16 +100,21 @@ def apply(params, x: jnp.ndarray, cfg: ModelConfig, rt: RuntimeConfig
         jnp.einsum("bsd,dh->bsh", x, params["wdt"]).astype(jnp.float32)
         + params["dt_bias"].astype(jnp.float32))
 
-    xs = jax.nn.silu(_causal_conv(xs, params["conv_x"]))
-    Bc = jax.nn.silu(_causal_conv(Bc, params["conv_B"]))
-    Cc = jax.nn.silu(_causal_conv(Cc, params["conv_C"]))
+    xs = _causal_conv(xs, params["conv_x"])
+    Bc = _causal_conv(Bc, params["conv_B"])
+    Cc = _causal_conv(Cc, params["conv_C"])
+    if "conv_bias" in params:
+        bx, bB, bC = jnp.split(params["conv_bias"],
+                               [cfg.d_inner, cfg.d_inner + cfg.ssm_state])
+        xs, Bc, Cc = xs + bx, Bc + bB, Cc + bC
+    xs, Bc, Cc = jax.nn.silu(xs), jax.nn.silu(Bc), jax.nn.silu(Cc)
 
     A = -jnp.exp(params["A_log"])
     y = _ssd_dispatch(xs.reshape(b, s, h, p), dt, A, Bc, Cc, params["D"], rt)
     y = y.reshape(b, s, cfg.d_inner)
 
     out = fused_ops.fused_stack_apply(
-        _gated_norm_program(1e-6), {"y": y, "z": z},
+        _gated_norm_program(cfg.norm_eps), {"y": y, "z": z},
         {"scale": params["norm_scale"]}, mode=rt.mode)["o"]
     return jnp.einsum("bse,ed->bsd", out, params["wo"])
 
@@ -159,6 +170,8 @@ def decode(params, x_t: jnp.ndarray, cache: MambaCache, cfg: ModelConfig,
     w_all = jnp.concatenate(
         [params["conv_x"], params["conv_B"], params["conv_C"]], axis=-1)
     conv_out = jnp.einsum("bwc,wc->bc", window, w_all)
+    if "conv_bias" in params:
+        conv_out = conv_out + params["conv_bias"]
     conv_out = jax.nn.silu(conv_out)
     xs_c, B_c, C_c = jnp.split(conv_out, [di, di + n], axis=-1)
 
@@ -168,7 +181,8 @@ def decode(params, x_t: jnp.ndarray, cache: MambaCache, cfg: ModelConfig,
     y = y.reshape(b, di)
 
     out = fused_ops.fused_stack_apply(
-        _gated_norm_program(1e-6), {"y": y[:, None], "z": z[:, None]},
+        _gated_norm_program(cfg.norm_eps),
+        {"y": y[:, None], "z": z[:, None]},
         {"scale": params["norm_scale"]}, mode=rt.mode)["o"]
     new_conv = window[:, 1:].astype(cache.conv.dtype)
     if active is not None:

@@ -124,9 +124,9 @@ def lint_lm_arch(arch: str, device: resource.DeviceSpec,
     cfg = get_config(arch)
     has_bias = cfg.norm == "layer"
     cases = [
-        (stacks.norm_program(cfg.norm, 1e-6, has_bias),
+        (stacks.norm_program(cfg.norm, cfg.norm_eps, has_bias),
          {"x": (rows, cfg.d_model)}),
-        (stacks.addnorm_program(cfg.norm, 1e-6, has_bias),
+        (stacks.addnorm_program(cfg.norm, cfg.norm_eps, has_bias),
          {"x": (rows, cfg.d_model), "res": (rows, cfg.d_model)}),
     ]
     if cfg.d_ff:

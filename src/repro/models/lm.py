@@ -6,7 +6,9 @@ stack of blocks, fused BrainSlug norm/act chains, final norm, vocab head,
 loss.  Family differences are *data*, not code paths:
 
 * ``layer_plan(cfg)`` describes the repeating super-block (e.g. llama4:
-  ``("attn_dense", "attn_moe")``; zamba2: 13 mamba + 1 shared-attn) and the
+  ``("attn_dense", "attn_moe")``; zamba2: 13 mamba + 1 shared-attn;
+  granite-4.0-h: the config's ``layer_pattern`` of ten distinct layers,
+  each a Mamba or attention mixer followed by an MoE FFN) and the
   heterogeneous tail.
 * Blocks are scanned (``jax.lax.scan``) over stacked per-layer params —
   compile time and HLO size stay bounded for 64-81-layer models.
@@ -43,7 +45,17 @@ class LayerPlan:
         return "shared_attn" in self.superblock or "shared_attn" in self.tail
 
 
+#: sub-layer kinds whose mixer is Mamba-2 (with recurrent decode state)
+MAMBA_KINDS = ("mamba", "mamba_moe")
+
+
 def layer_plan(cfg: ModelConfig) -> LayerPlan:
+    if cfg.layer_pattern:
+        p = len(cfg.layer_pattern)
+        if cfg.n_layers % p:
+            raise ValueError(f"{cfg.name}: n_layers % len(layer_pattern) "
+                             f"!= 0")
+        return LayerPlan(tuple(cfg.layer_pattern), cfg.n_layers // p, ())
     if cfg.family == "ssm":
         return LayerPlan(("mamba",), cfg.n_layers, ())
     if cfg.family == "hybrid":
@@ -71,9 +83,12 @@ def _init_sub(key, kind: str, cfg: ModelConfig, dtype) -> dict:
         return {"norm1": dense.norm_init(ks[0], cfg, dtype),
                 "mixer": mamba2.init(ks[1], cfg, dtype)}
     p = {"norm1": dense.norm_init(ks[0], cfg, dtype),
-         "attn": attention.init(ks[1], cfg, dtype),
          "norm2": dense.norm_init(ks[2], cfg, dtype)}
-    if kind == "attn_moe":
+    if kind == "mamba_moe":
+        p["mixer"] = mamba2.init(ks[1], cfg, dtype)
+    else:
+        p["attn"] = attention.init(ks[1], cfg, dtype)
+    if kind.endswith("_moe"):
         p["moe"] = moe.init(ks[3], cfg, dtype)
     else:                                   # attn_dense / shared_attn
         p["mlp"] = dense.init(ks[3], cfg, dtype=dtype)
@@ -127,28 +142,59 @@ def init(key, cfg: ModelConfig) -> tuple[Any, Any]:
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+def _add_norm(x, resid, p, cfg: ModelConfig, rt: RuntimeConfig):
+    return stacks.add_norm(x, resid, p["scale"], p.get("bias"),
+                           norm=cfg.norm, eps=cfg.norm_eps, mode=rt.mode)
+
+
+def _final_norm(params, h, cfg: ModelConfig, rt: RuntimeConfig):
+    p = params["final_norm"]
+    return stacks.apply_norm(h, p["scale"], p.get("bias"), norm=cfg.norm,
+                             eps=cfg.norm_eps, mode=rt.mode)
+
+
+def _residual(x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """A sub-layer's output as it joins the residual stream (scaled in
+    float32: 0.22 is not a bfloat16 number)."""
+    if cfg.residual_multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * cfg.residual_multiplier).astype(x.dtype)
+
+
+def _ffn(p, h, cfg: ModelConfig, rt: RuntimeConfig, *,
+         dropless: bool = False):
+    """The FFN after a mixer: (output, MoE aux or None)."""
+    if "moe" not in p:
+        return dense.apply(p["mlp"], h, cfg, rt), None
+    # the scopes ("moe", "shared", "mamba", "attn") reach the compiled
+    # step's op metadata, where device time is found by part
+    with jax.named_scope("moe"):
+        out, aux = moe.apply(p["moe"], h, cfg, rt, dropless=dropless)
+    if cfg.shared_expert_ff:
+        with jax.named_scope("shared"):
+            out = out + moe.shared(p["moe"], h, cfg, rt)
+    return out, aux
+
+
 def _apply_sub(kind: str, p, carry, cfg: ModelConfig, rt: RuntimeConfig,
                shared_params=None):
     resid, pending, aux = carry
-    norm_kw = dict(norm=cfg.norm, mode=rt.mode)
-    if kind == "mamba":
-        h1, resid = stacks.add_norm(pending, resid, p["norm1"]["scale"],
-                                    p["norm1"].get("bias"), **norm_kw)
-        out = mamba2.apply(p["mixer"], h1, cfg, rt)
-        return (resid, out, aux)
     if kind == "shared_attn":
         p = shared_params
-    h1, resid = stacks.add_norm(pending, resid, p["norm1"]["scale"],
-                                p["norm1"].get("bias"), **norm_kw)
-    attn_out = attention.apply(p["attn"], h1, cfg, rt)
-    h2, resid = stacks.add_norm(attn_out, resid, p["norm2"]["scale"],
-                                p["norm2"].get("bias"), **norm_kw)
-    if "moe" in p:
-        out, moe_aux = moe.apply(p["moe"], h2, cfg, rt)
-        aux = {k: aux[k] + moe_aux[k] for k in aux}
+    h1, resid = _add_norm(pending, resid, p["norm1"], cfg, rt)
+    if kind in MAMBA_KINDS:
+        with jax.named_scope("mamba"):
+            mixed = _residual(mamba2.apply(p["mixer"], h1, cfg, rt), cfg)
+        if kind == "mamba":
+            return (resid, mixed, aux)
     else:
-        out = dense.apply(p["mlp"], h2, cfg, rt)
-    return (resid, out, aux)
+        with jax.named_scope("attn"):
+            mixed = _residual(attention.apply(p["attn"], h1, cfg, rt), cfg)
+    h2, resid = _add_norm(mixed, resid, p["norm2"], cfg, rt)
+    out, moe_aux = _ffn(p, h2, cfg, rt)
+    if moe_aux is not None:
+        aux = {k: aux[k] + moe_aux[k] for k in aux}
+    return (resid, _residual(out, cfg), aux)
 
 
 def _remat(fn, rt: RuntimeConfig):
@@ -160,12 +206,22 @@ def _remat(fn, rt: RuntimeConfig):
     return fn
 
 
+def _embed(params, tokens, cfg: ModelConfig) -> jnp.ndarray:
+    """Token embeddings times ``cfg.embedding_multiplier`` (unset:
+    sqrt(d_model) for a tied table, else none)."""
+    x = params["embed"][tokens]
+    m = cfg.embedding_multiplier
+    if m is None and cfg.tie_embeddings:
+        m = cfg.d_model ** 0.5
+    if m is not None:
+        x = x * jnp.asarray(m, x.dtype)
+    return x
+
+
 def embed_inputs(params, batch: dict, cfg: ModelConfig) -> jnp.ndarray:
     if cfg.frontend == "audio_frames":
         return batch["frames"] @ params["frontend_proj"]
-    x = params["embed"][batch["tokens"]]
-    if cfg.tie_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = _embed(params, batch["tokens"], cfg)
     if cfg.frontend == "vision_patches":
         pre = batch["patches"] @ params["frontend_proj"]
         x = jnp.concatenate([pre.astype(x.dtype), x], axis=1)
@@ -198,11 +254,7 @@ def hidden(params, batch: dict, cfg: ModelConfig, rt: RuntimeConfig
             return (_apply_sub("mamba", p["sub0"], c, cfg, rt), None)
         carry, _ = jax.lax.scan(_remat(tail_body, rt), carry, params["tail"])
     resid, pending, aux = carry
-    h = resid + pending
-
-    h = stacks.apply_norm(h, params["final_norm"]["scale"],
-                          params["final_norm"].get("bias"), norm=cfg.norm,
-                          mode=rt.mode)
+    h = _final_norm(params, resid + pending, cfg, rt)
     return h, aux
 
 
@@ -223,8 +275,12 @@ def prefill(params, batch: dict, cfg: ModelConfig, rt: RuntimeConfig
 
 def _logits(params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     if cfg.tie_embeddings:
-        return jnp.einsum("bsd,vd->bsv", h, params["embed"])
-    return jnp.einsum("bsd,dv->bsv", h, params["out_head"])
+        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
+    else:
+        logits = jnp.einsum("bsd,dv->bsv", h, params["out_head"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
 
 
 def _nll_from_hidden(params, h, labels, cfg: ModelConfig,
@@ -361,7 +417,7 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int,
         raise ValueError("paged kv_layout requires kv_num_blocks >= 1")
 
     def sub_cache(kind: str):
-        if kind == "mamba":
+        if kind in MAMBA_KINDS:
             return mamba2.init_cache(cfg, batch, dtype)
         if kv_layout == "paged":
             return attention.init_paged_cache(cfg, batch, kv_num_blocks,
@@ -436,30 +492,36 @@ def copy_blocks(cache: dict, src: jnp.ndarray, dst: jnp.ndarray) -> dict:
 
 def _decode_sub(kind: str, p, cache, carry, cfg, rt, shared_params=None,
                 active=None, block_table=None):
-    resid, pending = carry
-    norm_kw = dict(norm=cfg.norm, mode=rt.mode)
-    if kind == "mamba":
-        h1, resid = stacks.add_norm(pending, resid, p["norm1"]["scale"],
-                                    p["norm1"].get("bias"), **norm_kw)
-        out, cache = mamba2.decode(p["mixer"], h1, cache, cfg, rt,
-                                   active=active)
-        return (resid, out), cache
+    """One sub-layer of a decode step.  ``carry`` is ``(resid, pending)``,
+    or ``(resid, pending, held)`` where ``held`` counts the expert
+    assignments of active lanes that landed on the experts held here."""
+    resid, pending = carry[:2]
     if kind == "shared_attn":
         p = shared_params
-    h1, resid = stacks.add_norm(pending, resid, p["norm1"]["scale"],
-                                p["norm1"].get("bias"), **norm_kw)
-    attn_out, cache = attention.decode(p["attn"], h1, cache, cfg, rt,
-                                       active=active,
-                                       block_table=block_table)
-    h2, resid = stacks.add_norm(attn_out, resid, p["norm2"]["scale"],
-                                p["norm2"].get("bias"), **norm_kw)
-    if "moe" in p:
-        # serving is dropless: dropping a live request's token to a
-        # capacity limit is a training-only trade-off
-        out, _ = moe.apply(p["moe"], h2, cfg, rt, dropless=True)
+    h1, resid = _add_norm(pending, resid, p["norm1"], cfg, rt)
+    if kind in MAMBA_KINDS:
+        with jax.named_scope("mamba"):
+            mixed, cache = mamba2.decode(p["mixer"], h1, cache, cfg, rt,
+                                         active=active)
     else:
-        out = dense.apply(p["mlp"], h2, cfg, rt)
-    return (resid, out), cache
+        with jax.named_scope("attn"):
+            mixed, cache = attention.decode(p["attn"], h1, cache, cfg, rt,
+                                            active=active,
+                                            block_table=block_table)
+    mixed = _residual(mixed, cfg)
+    if kind == "mamba":
+        return (resid, mixed) + carry[2:], cache
+    h2, resid = _add_norm(mixed, resid, p["norm2"], cfg, rt)
+    # serving is dropless: dropping a live request's token to a capacity
+    # limit is a training-only trade-off
+    out, moe_aux = _ffn(p, h2, cfg, rt, dropless=True)
+    rest = carry[2:]
+    if rest and moe_aux is not None:
+        held = moe_aux["held"][:, 0]
+        if active is not None:
+            held = jnp.where(active, held, 0)
+        rest = (rest[0] + jnp.sum(held),)
+    return (resid, _residual(out, cfg)) + rest, cache
 
 
 # ---------------------------------------------------------------------------
@@ -597,8 +659,8 @@ def tp_param_specs(params: Any, model_extent: int) -> Any:
 def decode_step(params, cache: dict, tokens_t: jnp.ndarray,
                 cfg: ModelConfig, rt: RuntimeConfig,
                 active: jnp.ndarray | None = None,
-                block_tables: jnp.ndarray | None = None
-                ) -> tuple[jnp.ndarray, dict]:
+                block_tables: jnp.ndarray | None = None, *,
+                count_experts: bool = False) -> tuple:
     """One serving step: tokens_t (B, 1) -> (logits (B, 1, V), new cache).
 
     ``active`` is an optional (B,) bool slot mask for mixed continuous-
@@ -610,11 +672,13 @@ def decode_step(params, cache: dict, tokens_t: jnp.ndarray,
     ``block_tables`` (B, MB) int32 is required for (and only for) a paged
     KV cache: one table addresses every layer's pool, closed over as a
     scan constant.
+
+    ``count_experts=True`` returns a third value: the int32 count of the
+    active lanes' expert assignments that landed on the experts held here
+    (``cfg.expert_share``), over every MoE layer.
     """
     plan = layer_plan(cfg)
-    x = params["embed"][tokens_t]
-    if cfg.tie_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = _embed(params, tokens_t, cfg)
     shared = params.get("shared_attn")
     new_cache: dict[str, Any] = {}
 
@@ -629,6 +693,8 @@ def decode_step(params, cache: dict, tokens_t: jnp.ndarray,
         return carry, out_cache
 
     carry = (x, jnp.zeros_like(x))
+    if count_experts:
+        carry += (jnp.zeros((), jnp.int32),)
     if "blocks" in params:
         carry, new_cache["blocks"] = jax.lax.scan(
             block_body, carry, (params["blocks"], cache["blocks"]))
@@ -640,9 +706,7 @@ def decode_step(params, cache: dict, tokens_t: jnp.ndarray,
             return c, {"sub0": out}
         carry, new_cache["tail"] = jax.lax.scan(
             tail_body, carry, (params["tail"], cache["tail"]))
-    resid, pending = carry
-    h = resid + pending
-    h = stacks.apply_norm(h, params["final_norm"]["scale"],
-                          params["final_norm"].get("bias"), norm=cfg.norm,
-                          mode=rt.mode)
+    h = _final_norm(params, carry[0] + carry[1], cfg, rt)
+    if count_experts:
+        return _logits(params, h, cfg), new_cache, carry[2]
     return _logits(params, h, cfg), new_cache
