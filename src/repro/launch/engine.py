@@ -530,6 +530,14 @@ class Engine:
         self._reset = _jitted_reset
         self._copy = _jitted_copy
 
+    def init_cache(self) -> dict:
+        """A fresh decode cache for every slot, in the model's dtype (the
+        Mamba SSM state in float32): what a run starts from, and the
+        shapes the mesh plan partitions."""
+        return lm.init_decode_cache(
+            self.cfg, self.slots, self.max_len, kv_layout=self.kv_layout,
+            kv_num_blocks=self.kv_num_blocks, kv_block_size=self.block_size)
+
     def _build_sharded_step(self, mesh):
         """Plan the decode-cache partition, verify it, localize the config
         for tensor-sharded heads, commit the params, and return the jitted
@@ -542,12 +550,7 @@ class Engine:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         axes = partition_mod.MeshAxes.from_mesh(mesh)
-        cache_shapes = jax.eval_shape(
-            lambda: lm.init_decode_cache(
-                self.cfg, self.slots, self.max_len, dtype=jnp.float32,
-                kv_layout=self.kv_layout,
-                kv_num_blocks=self.kv_num_blocks,
-                kv_block_size=self.block_size))
+        cache_shapes = jax.eval_shape(self.init_cache)
         plan = partition_mod.plan_decode_cache(
             cache_shapes, self.rt.serve_partition, axes, slots=self.slots,
             head_extents=(self.cfg.n_heads, self.cfg.n_kv_heads))
@@ -790,14 +793,7 @@ class Engine:
         ttfts: list[float] = []
         n_ttft_pending = 0      # first-token commits awaiting that same
         # shared clock read (TTFT = admission wait + prefill)
-        if paged:
-            cache = lm.init_decode_cache(
-                self.cfg, B, self.max_len, dtype=jnp.float32,
-                kv_layout="paged", kv_num_blocks=self.kv_num_blocks,
-                kv_block_size=bs)
-        else:
-            cache = lm.init_decode_cache(self.cfg, B, self.max_len,
-                                         dtype=jnp.float32)
+        cache = self.init_cache()
         t0 = time.perf_counter()
 
         def complete(s_idx: int, req: Request, prompt, gen) -> None:

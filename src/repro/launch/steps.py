@@ -59,9 +59,8 @@ def decode_input_specs(cfg: ModelConfig, shape: ShapeConfig
     """(tokens_t spec, cache spec tree) for one decode step with a KV/state
     cache sized for ``shape.seq_len``."""
     b = shape.global_batch
-    cache_dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     cache = jax.eval_shape(
-        lambda: lm.init_decode_cache(cfg, b, shape.seq_len, cache_dtype))
+        lambda: lm.init_decode_cache(cfg, b, shape.seq_len))
     tokens = {"tokens": jax.ShapeDtypeStruct((b, 1), jnp.int32)}
     return tokens, cache
 

@@ -97,7 +97,7 @@ def _init_sub(key, kind: str, cfg: ModelConfig, dtype) -> dict:
 
 def init(key, cfg: ModelConfig) -> tuple[Any, Any]:
     """Returns (params, logical_axes) trees."""
-    dtype = jnp.dtype(cfg.dtype) if cfg.dtype != "float32" else jnp.float32
+    dtype = jnp.dtype(cfg.dtype)
     plan = layer_plan(cfg)
     keys = jax.random.split(key, 8)
 
@@ -397,10 +397,15 @@ def tail_decode(tail_params, tail_cache, x, cfg: ModelConfig,
 # ---------------------------------------------------------------------------
 
 def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int,
-                      dtype=jnp.bfloat16, *, kv_layout: str = "dense",
+                      dtype=None, *, kv_layout: str = "dense",
                       kv_num_blocks: int = 0,
                       kv_block_size: int = 16) -> dict:
     """Decode cache for every layer, stacked along the scan axis.
+
+    ``dtype`` (default: the model's, ``cfg.dtype``, which ``init`` makes
+    the weights in) is that of the K/V and the Mamba conv window; every
+    value written there is already of the model's dtype, so a wider cache
+    only moves more bytes.  The Mamba SSM state is float32 regardless.
 
     ``kv_layout="paged"`` swaps each attention layer's dense (B, G,
     max_len, hd) reservation for a pool of ``kv_num_blocks`` physical
@@ -415,6 +420,9 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int,
                          f"allowed: 'dense' | 'paged'")
     if kv_layout == "paged" and kv_num_blocks < 1:
         raise ValueError("paged kv_layout requires kv_num_blocks >= 1")
+
+    if dtype is None:
+        dtype = jnp.dtype(cfg.dtype)
 
     def sub_cache(kind: str):
         if kind in MAMBA_KINDS:

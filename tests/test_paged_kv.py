@@ -4,17 +4,24 @@ and dense-vs-paged engine parity."""
 from __future__ import annotations
 
 import dataclasses
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
+from repro.configs.base import RuntimeConfig
 from repro.core import verify
 from repro.kernels.attention import ops as attn_ops
 from repro.kernels.attention import ref as attn_ref
 from repro.launch.engine import BlockAllocator, Engine, PrefixCache, Request
 from repro.launch.serve import ServeConfig, Server
+from repro.models import lm
+
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "bench"
 
 
 @pytest.fixture(scope="module")
@@ -372,3 +379,84 @@ class TestPagedEngine:
         with pytest.raises(ValueError, match="kv_num_blocks"):
             paged_server.engine(kv_layout="paged", kv_block_size=4,
                                 kv_num_blocks=2)
+
+
+# ---------------------------------------------------------------------------
+# the decode cache in the model's dtype
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("arch", ["deepseek-7b", "granite-4.0-h-small"],
+                         ids=["dense", "hybrid"])
+def test_engine_cache_leaves_take_the_model_dtype(arch, dtype):
+    """The cache the engine builds (for a run and for its mesh plan): K/V,
+    paged or dense, and the Mamba conv window in the model's dtype; the
+    SSM state float32; lengths int32."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype=dtype)
+    params, _ = lm.init(jax.random.PRNGKey(0), cfg)
+    want = {"k": dtype, "v": dtype, "k_pool": dtype, "v_pool": dtype,
+            "conv": dtype, "state": "float32", "length": "int32"}
+    for layout in ("paged", "dense"):
+        rt = RuntimeConfig(mode="xla", kv_layout=layout, kv_block_size=4)
+        eng = Engine(cfg, params, rt, slots=2, max_len=16)
+        got = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+                jax.eval_shape(eng.init_cache)):
+            got.setdefault(path[-1].name, set()).add(str(leaf.dtype))
+        assert got == {k: {want[k]} for k in got}, (layout, got)
+        kv = {"k_pool", "v_pool"} if layout == "paged" else {"k", "v"}
+        assert kv <= set(got)
+        assert ({"conv", "state"} <= set(got)) == (arch != "deepseek-7b")
+
+
+def _bench_case(name: str, monkeypatch):
+    """The benchmark's CPU-size ``name`` configuration at bfloat16: the
+    program's config and the benchmark's seeded weights in its tree."""
+    monkeypatch.syspath_prepend(str(BENCH))
+    import harness
+
+    cfg = json.loads((BENCH.parent / "tests" / "bench" / "tiny" / "configs"
+                      / f"{name}.json").read_text())
+    cfg["torch_dtype"] = "bfloat16"
+    ref = harness.load_module(BENCH / "configs" / f"{name}.py")
+    w = jax.jit(lambda k: ref.init(cfg, k))(jax.random.PRNGKey(5))
+    if name == "deepseek-7b":
+        drv = harness.load_module(BENCH / "drivers" / "serve.py")
+        return cfg, drv.program_config(cfg), drv.program_params(w)
+    drv = harness.load_module(BENCH / "drivers" / "serve_hybrid.py")
+    return cfg, drv.program_config(cfg), drv.program_params(w, cfg)
+
+
+@pytest.mark.parametrize("name", ["deepseek-7b", "granite-4.0-h-small"])
+def test_model_dtype_cache_serves_the_same_logits(name, monkeypatch):
+    """A bfloat16 model writes only bfloat16 values into its K/V pools and
+    conv window, so storing them in bfloat16 instead of float32 gives
+    bit-identical logits: 40 tokens through a paged cache and a shuffled
+    block table, in brainslug mode (the Pallas paged-decode kernel)."""
+    cfg, mc, params = _bench_case(name, monkeypatch)
+    assert mc.dtype == "bfloat16"
+    bs, n, slots, mb = 16, 40, 2, 3
+    rt = RuntimeConfig(mode="brainslug", kv_layout="paged", kv_block_size=bs)
+    table = jnp.asarray(np.random.default_rng(1).permutation(
+        slots * mb).reshape(slots, mb), jnp.int32)
+    tokens = np.random.default_rng(2).integers(0, cfg["vocab_size"],
+                                               (n, slots, 1))
+    step = jax.jit(lambda c, t: lm.decode_step(params, c, t, mc, rt,
+                                               block_tables=table)[:2])
+    logits = {}
+    for dtype in (jnp.float32, None):
+        cache = lm.init_decode_cache(mc, slots, mb * bs, dtype=dtype,
+                                     kv_layout="paged",
+                                     kv_num_blocks=slots * mb,
+                                     kv_block_size=bs)
+        got = []
+        for t in tokens:
+            out, cache = step(cache, jnp.asarray(t, jnp.int32))
+            got.append(np.asarray(out.astype(jnp.float32)))
+        logits[dtype] = np.stack(got)
+        pools = {x.dtype for path, x in
+                 jax.tree_util.tree_leaves_with_path(cache)
+                 if path[-1].name in ("k_pool", "v_pool")}
+        assert pools == {jnp.dtype(dtype or jnp.bfloat16)}
+    assert np.isfinite(logits[None]).all()
+    assert np.array_equal(logits[jnp.float32], logits[None])

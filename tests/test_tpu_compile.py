@@ -6,9 +6,15 @@ use, loads from HBM refs, value ops Mosaic has no lowering for.  These tests
 lower and compile each main-path kernel for one chip of a described
 ``v5e:2x2`` topology — no chip is attached; nothing runs — at the widths the
 serve engine and the paper path use (deepseek-7b: 32 heads of 128, d_ff
-11008; VGG stage at 224x224 ImageNet resolution).
+11008; VGG stage at 224x224 ImageNet resolution).  The serve engine's
+whole mixed step is compiled too, to read what the compiler made of the
+KV pool around the kernel.
 """
 from __future__ import annotations
+
+import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,12 +22,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import kernels
+from repro.configs import get_config
+from repro.configs.base import RuntimeConfig
 from repro.core import ir
 from repro.kernels.attention import decode
 from repro.kernels.fused_stack import nhwc, nhwc_bwd
 from repro.kernels.fused_stack import ops as fused_ops
 from repro.kernels.swiglu import swiglu
+from repro.launch import engine as engine_mod
 from repro.layers import stacks
+from repro.models import lm
 
 SLOTS, HEADS, HEAD_DIM, D_FF = 8, 32, 128, 11008   # deepseek-7b serving
 MAX_LEN, BLOCK = 2048, 16
@@ -58,15 +68,23 @@ def no_compile_cache():
 @pytest.fixture
 def compile_for(one_chip, no_compile_cache, monkeypatch):
     """Lower and compile ``fn`` for the described chip with the kernels in
-    compiled (Mosaic) mode; returns the HLO text."""
+    compiled (Mosaic) mode; returns the HLO text.  Each argument is a
+    ``(shape, dtype)`` pair or a pytree of ``jax.ShapeDtypeStruct``; a
+    ``fn`` that is jitted already keeps its own options (donation)."""
     # The backend here is the CPU, which derives interpret mode: steer the
     # kernels to the chip's compiled path for this test only.
     monkeypatch.setattr(kernels, "pallas_interpret", lambda: False)
 
+    def on_chip(a):
+        if isinstance(a, tuple) and len(a) == 2 and isinstance(a[0], tuple):
+            return jax.ShapeDtypeStruct(*a, sharding=one_chip)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), a)
+
     def compile_(fn, *shapes):
-        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                for s, d in shapes]
-        text = jax.jit(fn).lower(*args).compile().as_text()
+        args = [on_chip(a) for a in shapes]
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        text = jitted.lower(*args).compile().as_text()
         assert "tpu_custom_call" in text      # the Mosaic kernel is there
         return text
     return compile_
@@ -143,10 +161,42 @@ def test_nhwc_backward_compiles(compile_for):
                 IMAGE, CHANNEL, CHANNEL, ((1, 112, 112, 64), jnp.float32))
 
 
+def test_engine_step_keeps_the_bf16_pool_in_bf16(compile_for):
+    """The serve engine's mixed step at deepseek-7b widths (2 layers, 8
+    slots, a small paged pool): every buffer the size of one layer's pool
+    or of the layer-stacked pool is bfloat16, the model's dtype, and no
+    convert reads one: the per-token scatter, the layer scan's slices and
+    the kernel's operands all stay in the pool's dtype."""
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=2)
+    assert cfg.dtype == "bfloat16"
+    mb = 9                                    # blocks per slot: 144 tokens
+    n_blocks = SLOTS * mb
+    rt = RuntimeConfig(mode="brainslug", kv_layout="paged",
+                       kv_block_size=BLOCK)
+    eng = engine_mod.Engine(
+        cfg, jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0), cfg)[0]),
+        rt, slots=SLOTS, max_len=mb * BLOCK)
+    vec = ((SLOTS,), jnp.int32)
+    text = compile_for(eng._step, eng.params, jax.eval_shape(eng.init_cache),
+                       ((SLOTS, mb), jnp.int32), ((SLOTS, 8), jnp.int32),
+                       vec, vec, vec, ((SLOTS,), jnp.float32),
+                       ((2,), jnp.uint32))
+    pool = n_blocks * cfg.n_kv_heads * BLOCK * cfg.head_dim
+    sizes = (pool, cfg.n_layers * pool)
+
+    def pool_sized(dims: str) -> bool:
+        return math.prod(int(d) for d in dims.split(",") if d) in sizes
+
+    dtypes = {dt for dt, dims in re.findall(r"\b(\w+)\[([\d,]*)\]", text)
+              if pool_sized(dims)}
+    assert dtypes == {"bf16"}
+    assert not [dims for dims in re.findall(
+        r"convert\(\w+\[([\d,]*)\]", text) if pool_sized(dims)]
+
+
 def _kernel_bodies(text: str) -> list[bytes]:
     """The Mosaic body of every Pallas kernel call in a compiled program."""
     import base64
-    import re
 
     return [base64.b64decode(m) for m in re.findall(
         r'custom_call_target="tpu_custom_call".*?"body":"([A-Za-z0-9+/=]+)"',
